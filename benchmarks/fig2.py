@@ -52,7 +52,7 @@ import time
 import jax
 import numpy as np
 
-from repro import tasks
+from repro import compile_cache, tasks
 from repro.core import channel, power_control as pcm, scenarios as scn
 from repro.core.theory import OTAParams
 from repro.fl.driver import run_fleet_task
@@ -766,6 +766,7 @@ def main(argv=None) -> None:
                     help="force N host-platform (CPU) devices per "
                          "process (multi-process CPU smoke)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.coordinator:
         if args.num_processes is None or args.process_id is None:
             raise SystemExit("--coordinator needs --num-processes and "

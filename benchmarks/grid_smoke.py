@@ -180,6 +180,9 @@ def launch(num_processes: int = 2, local_devices: int = 4,
     # each worker forces its OWN device count via --local-devices; a
     # parent-level forced count would leak into both
     env.pop("XLA_FLAGS", None)
+    # the workers are a CPU bring-up proof: on an accelerator host both
+    # would otherwise try to take the same chip
+    env["JAX_PLATFORMS"] = "cpu"
     procs = []
     for i in range(num_processes):
         cmd = [sys.executable, "-m", "benchmarks.grid_smoke",

@@ -19,10 +19,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.kernels import ops, ref
 
-UPLINKS = ("f32", "bf16", "int8")
-_WIRE_BYTES = {"f32": 4, "bf16": 2, "int8": 1}
+UPLINKS = ops.UPLINK_DTYPES
 
 
 def _time(fn, *args, iters=5):
@@ -48,14 +48,6 @@ def run() -> list:
     err = float(jnp.max(jnp.abs(out_k - ref.ota_aggregate_ref(g, s, z, ns))))
     rows.append({"bench": "ota_aggregate_d814k", "us_per_call": round(t_ref, 1),
                  "kernel_max_err": err})
-
-    # blocked attention 2k, window vs full
-    q = jax.random.normal(key, (1, 2048, 8, 64), jnp.float32)
-    k = jax.random.normal(key, (1, 2048, 2, 64), jnp.float32)
-    v = jax.random.normal(key, (1, 2048, 2, 64), jnp.float32)
-    fn_full = jax.jit(lambda q, k, v: ref.attention_ref(q, k, v, causal=True))
-    rows.append({"bench": "attention_ref_2k_full",
-                 "us_per_call": round(_time(fn_full, q, k, v, iters=3), 1)})
 
     # SSD scan (model path) vs sequential oracle, S=1024
     b, s_, h, p, gsz, n = 1, 1024, 8, 64, 1, 64
@@ -124,7 +116,7 @@ def round_step_rows(n: int = 10, d: int = 814_090, iters: int = 5) -> list:
         if base is None:
             base = out
         err = float(jnp.max(jnp.abs(out - base)))
-        uplink_mb = n * d * _WIRE_BYTES[ud] / 1e6
+        uplink_mb = n * d * ops.UPLINK_WIRE_BYTES[ud] / 1e6
         # one fused pass: wire in + z in + params in + params out (f32)
         fused_mb = uplink_mb + 3 * d * 4 / 1e6
         # unfused adds a ghat write + read between the two launches
@@ -153,9 +145,9 @@ def round_step_equivalence(n: int = 4, d: int = 5000) -> float:
     ns, eta = jnp.float32(0.25), jnp.float32(0.05)
     worst = 0.0
     for ud in UPLINKS:
-        wire, q_scale = ops.quantize_uplink(g, ud)
-        out = ops.ota_round_step(wire, s, z, ns, p, eta, q_scale,
+        out = ops.ota_round_step(g, s, z, ns, p, eta, uplink_dtype=ud,
                                  interpret=True)
+        wire, q_scale = ops.quantize_uplink(g, ud)
         exp = ref.ota_round_step_ref(wire, s, z, ns, p, eta,
                                      q_scale=q_scale)
         worst = max(worst, float(jnp.max(jnp.abs(out - exp))))
@@ -169,6 +161,7 @@ def main(argv=None):
                     help="reduced sizes + interpret-mode equivalence gate "
                          "(asserts; CI benchmark-smoke)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.smoke:
         err = round_step_equivalence()
         assert err < 2e-5, f"interpret-mode round_step err {err}"

@@ -1,9 +1,13 @@
 """Roofline analysis (deliverable g): three terms per (arch x mesh) from the
 dry-run artifacts, dominant bottleneck, MODEL_FLOPS ratio.
 
-    compute    = HLO_FLOPs_per_device / peak_FLOP/s          (197 TF bf16)
-    memory     = HLO_bytes_per_device / HBM_bw               (819 GB/s)
-    collective = collective_bytes_per_device / link_bw       (~50 GB/s)
+    compute    = HLO_FLOPs_per_device / peak_FLOP/s          (bf16)
+    memory     = HLO_bytes_per_device / HBM_bw
+    collective = collective_bytes_per_device / link_bw
+
+Peaks come from ``CHIP_PEAKS``, keyed by the record's ``device_kind``; a
+device that is not in the table raises instead of borrowing another
+chip's numbers.
 
 HLO quantities are the loop-corrected per-device values (launch/cost.py).
 Caveats recorded in EXPERIMENTS.md: 'bytes accessed' is an upper bound on
@@ -17,11 +21,31 @@ import json
 import os
 
 from repro import configs
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 from repro.models.config import ModelConfig
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                             "dryrun")
+
+
+# Published per-chip peaks, keyed by jax's ``device_kind``.  Source: Google
+# Cloud, "TPU v5e" (system architecture): 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over four
+# links (50 GB/s per link).
+CHIP_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The peak table row of ``device_kind``; raises for a device with no
+    published peaks here (a CPU among them)."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table entry for device {device_kind!r} "
+                         f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 # ---------------------------------------------------------------------------
@@ -100,9 +124,10 @@ def roofline_row(record: dict) -> dict:
                       record["bytes_accessed_per_device"])
     coll = record.get("collective_bytes_corrected",
                       record["collective_bytes_per_device"]["total"])
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = byts / HBM_BW
-    t_coll = coll / ICI_BW
+    peaks = chip_peaks(record["device_kind"])
+    t_compute = flops / peaks["bf16_flops"]
+    t_memory = byts / peaks["hbm_bw"]
+    t_coll = coll / peaks["ici_link_bw"]
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -128,11 +153,10 @@ def roofline_row(record: dict) -> dict:
 # ota_round_step launch per uplink dtype, vs the unfused four-op chain.
 # ---------------------------------------------------------------------------
 
-_UPLINK_WIRE_BYTES = {"f32": 4, "bf16": 2, "int8": 1}
-
-
-def ota_round_step_roofline(n: int = 10, d: int = 814_090) -> list:
-    """Compute/memory terms of the fused round-step kernel at [N, D].
+def ota_round_step_roofline(device_kind: str, n: int = 10,
+                            d: int = 814_090) -> list:
+    """Compute/memory terms of the fused round-step kernel at [N, D] on
+    the chip ``device_kind`` names.
 
     Traffic of one fused launch: the [N, D] uplink at wire precision in,
     z + params in and params out at f32 — the unfused chain adds a ghat
@@ -144,19 +168,22 @@ def ota_round_step_roofline(n: int = 10, d: int = 814_090) -> list:
     fusion's saved ghat round-trip — and a narrower uplink — convert
     directly into wall time.
     """
+    peaks = chip_peaks(device_kind)
     rows = []
-    for ud, wire in _UPLINK_WIRE_BYTES.items():
+    from repro.kernels.ops import UPLINK_WIRE_BYTES
+
+    for ud, wire in UPLINK_WIRE_BYTES.items():
         fused_bytes = n * d * wire + 3 * d * 4
         unfused_bytes = fused_bytes + 2 * d * 4
         flops = 3.0 * n * d + 4.0 * d
-        t_compute = flops / PEAK_FLOPS_BF16
-        t_memory = fused_bytes / HBM_BW
+        t_compute = flops / peaks["bf16_flops"]
+        t_memory = fused_bytes / peaks["hbm_bw"]
         rows.append({
             "kernel": "ota_round_step", "uplink_dtype": ud,
             "n": n, "d": d,
             "compute_s": t_compute,
             "memory_s": t_memory,
-            "unfused_memory_s": unfused_bytes / HBM_BW,
+            "unfused_memory_s": unfused_bytes / peaks["hbm_bw"],
             "dominant": "compute" if t_compute > t_memory else "memory",
             "flops_per_byte": flops / fused_bytes,
             "fused_bytes_mb": fused_bytes / 1e6,
@@ -178,7 +205,9 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    import jax
+
     for row in run():
         print(row)
-    for row in ota_round_step_roofline():
+    for row in ota_round_step_roofline(jax.devices()[0].device_kind):
         print(row)

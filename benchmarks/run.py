@@ -61,14 +61,9 @@ def smoke(seed: int = 0) -> None:
     # a tiny batch solve must track the scipy oracle, and the adaptive
     # scheme must re-design inside a compiled Gauss-Markov fleet ---
     t0 = time.time()
-    from repro import solvers
-    from repro.core import sca as sca_mod, theory
-    from benchmarks.sca_bench import make_prm as solver_prm
-    prms = [solver_prm(6, s) for s in range(4)]
-    br = solvers.solve_batch(prms)
-    ref = sca_mod.solve_sca(prms[0]).objective
-    gap = br.objective[0] / ref - 1.0
-    assert abs(gap) < 1e-3, (br.objective[0], ref)
+    from benchmarks.sca_bench import batch_gap_vs_scipy
+    gap, br = batch_gap_vs_scipy()
+    assert abs(gap) < 1e-3, (gap, br.objective[0])
     assert np.all(np.isfinite(br.gamma)) and np.all(br.gamma > 0)
     print(_csv({"bench": "smoke_solver_batch4", "gap_vs_scipy": f"{gap:.2e}",
                 "objective": round(float(br.objective[0]), 4)}), flush=True)
@@ -161,6 +156,8 @@ def main(argv=None) -> None:
                     help="CI gate: short compiled-engine runs, asserts")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
 
     if args.smoke:
         smoke(seed=args.seed)

@@ -31,6 +31,20 @@ def make_prm(n: int, seed: int, d: int = 814090) -> OTAParams:
                      kappa_sq=4.0)
 
 
+def batch_gap_vs_scipy(n: int = 6, batch: int = 4, placement=None):
+    """Relative (P1) objective gap of a ``batch``-scenario JAX solve at
+    ``n`` devices against the scipy SLSQP oracle: every row against its own
+    SLSQP solve, the gap of largest magnitude returned.  ``placement`` as
+    in ``solvers.solve_batch``.  Returns (gap, solvers.BatchResult)."""
+    from repro import solvers
+
+    prms = [make_prm(n, s) for s in range(batch)]
+    br = solvers.solve_batch(prms, placement=placement)
+    gaps = [float(br.objective[i] / sca.solve_sca(p).objective - 1.0)
+            for i, p in enumerate(prms)]
+    return max(gaps, key=abs), br
+
+
 def run(num_seeds: int = 5, sizes=(10, 20, 50)) -> list:
     rows = []
     for n in sizes:

@@ -47,6 +47,7 @@ import sys
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.paper_mlp import CONFIG as PAPER
 from repro.core import power_control as pcm
 from repro.core import scenarios as scn
@@ -253,9 +254,12 @@ def _run_rss_probe(task_name: str, scenario_names, num_rounds: int,
                "--rounds", str(num_rounds), "--seed", str(seed),
                "--grid-seeds", str(num_seeds),
                "--scenarios", ",".join(scenario_names)]
+        # the probe is a host-RSS measurement, and the parent has touched
+        # JAX: on an accelerator host it holds the chip the child would need
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               cwd=os.path.join(os.path.dirname(__file__),
-                                               ".."))
+                                               ".."),
+                              env={**os.environ, "JAX_PLATFORMS": "cpu"})
         line = next((ln for ln in proc.stdout.splitlines()
                      if ln.startswith("RSS_PROBE ")), None)
         if proc.returncode != 0 or line is None:
@@ -425,6 +429,7 @@ def main(argv=None) -> None:
         _rss_probe_child(args.task, names, SCHEMES, args.rounds, args.seed,
                          args.grid_seeds, donate=args.rss_probe == "donate")
         return
+    compile_cache.enable()
 
     process_id = 0
     if args.coordinator:

@@ -235,7 +235,6 @@ def make_adaptive_sca(deployment: Deployment, prm: OTAParams,
     adaptive fleet should share those constants."""
     from repro import solvers
     from repro.solvers import theory_jax as tjx
-    from jax.experimental import enable_x64
 
     cfg = kw.pop("cfg", solvers.DEFAULT_CONFIG)
     res = solvers.solve(prm, cfg=cfg, **kw)
@@ -245,7 +244,7 @@ def make_adaptive_sca(deployment: Deployment, prm: OTAParams,
         rho = float(getattr(fading, "rho", 0.0))
         if state is None or rho == 0.0:
             return pc      # static CSI: nothing to track
-        with enable_x64():
+        with solvers.x64_scope():
             n = prm.num_devices
             state64 = jnp.asarray(state)                     # [..., N] complex
             batch = state64.shape[:-1]
@@ -292,7 +291,7 @@ def make_adaptive_sca(deployment: Deployment, prm: OTAParams,
         fparam = 1.0
 
     def redesign_cohort(pc: AdaptiveSCA, gains):
-        with enable_x64():
+        with solvers.x64_scope():
             n = prm.num_devices
             g = np.asarray(gains, np.float64)
             if g.shape[-1] != n:

@@ -97,20 +97,6 @@ def batch_logical():
     return "batch"
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (unchecked-replication mode).
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=False)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=False)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shmap
-    return _shmap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def grid_devices(mesh: Mesh, axes=("data", "model")) -> int:
     """Number of devices a flattened grid axis shards over: the product of
     the named mesh axis sizes."""
@@ -158,9 +144,10 @@ def shard_vmap(fn, mesh: Mesh, axes=("data", "model"), num_sharded: int = 1):
             return jax.vmap(fn, in_axes=(0,) * num_sharded
                             + (None,) * len(b_l))(*s_l, *b_l)
 
-        sm = shard_map(local, mesh,
-                       in_specs=(spec,) * num_sharded + (repl,) * len(bcast),
-                       out_specs=spec)
+        sm = jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(spec,) * num_sharded + (repl,) * len(bcast),
+            out_specs=spec, check_vma=False)
         out = sm(*(sharded if gp == g else tuple(map(pad, sharded))), *bcast)
         if gp != g:
             out = jax.tree.map(lambda a: a[:g], out)

@@ -455,7 +455,8 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
     # for the whole chunk instead of overlapping it.  With more than one
     # device visible the solve runs on the LAST one (the vmap fleet only
     # occupies the first); CPU executables are identical across host
-    # devices, so the lane cannot change a single bit — only walls.
+    # devices, so the lane cannot change a single bit — only walls.  Off
+    # the CPU backend the solve already runs on the host (x64_scope).
     stage_dev = None
     if pop_adaptive and len(jax.devices()) > 1:
         stage_dev = jax.devices()[-1]

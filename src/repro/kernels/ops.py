@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True — the kernel
-body runs as traced JAX ops, validating indexing/masking/accumulation logic;
-on TPU (the target) the same pallas_call lowers to Mosaic.  Wrappers handle
-padding to hardware-aligned tile sizes.
+On CPU the kernel executes with interpret=True — the kernel body runs as
+traced JAX ops, validating indexing/masking/accumulation logic; on TPU the
+same pallas_call lowers to Mosaic.  Wrappers pad and tile the operands to
+the kernel's lane-dense layout.
 """
 from __future__ import annotations
 
@@ -14,12 +14,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import flash_attention as fa
-from repro.kernels import ota_aggregate as oa
 from repro.kernels import round_step as rs
-from repro.kernels import ssd_scan as ss
 
-UPLINK_DTYPES = ("f32", "bf16", "int8")
+# bytes per element each uplink puts on the wire (an f32 uplink sends the
+# gradient dtype as is, so a bf16 gradient stays 2 bytes)
+UPLINK_WIRE_BYTES = {"f32": 4, "bf16": 2, "int8": 1}
+UPLINK_DTYPES = tuple(UPLINK_WIRE_BYTES)
 
 # int8 symmetric quantization: values map to [-127, 127] (the -128 code is
 # unused so the grid is symmetric around zero — standard for weights/grads)
@@ -30,18 +30,16 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int):
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x, size
+def _pad_to(x: jax.Array, axis: int, size: int) -> jax.Array:
+    """Zero-pad ``axis`` of ``x`` up to ``size``."""
     widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), size
+    widths[axis] = (0, size - x.shape[axis])
+    return jnp.pad(x, widths)
 
 
 def quantize_uplink(g: jax.Array, uplink_dtype: str):
-    """Device-side uplink quantization of the [N, D] precoded gradients.
+    """Device-side uplink quantization of the [N, ...] precoded gradients
+    (the raveled [N, D] stack, or its [N, rows, LANES] kernel tiling).
 
     Returns ``(wire, q_scale)`` — the array as transmitted plus the
     per-device symmetric dequantization scale (None when the wire dtype
@@ -51,7 +49,8 @@ def quantize_uplink(g: jax.Array, uplink_dtype: str):
             move a bit anywhere downstream.
       bf16  round-to-nearest-even cast; dequant is the f32 upcast.
       int8  per-device symmetric scale over the device's full raveled
-            gradient: scale_m = max_d |g[m, d]| / 127, wire = round(g /
+            gradient (every axis after the first; zero padding never moves
+            the max): scale_m = max_d |g[m, d]| / 127, wire = round(g /
             scale) clipped to [-127, 127].  Quantization error per element
             is bounded by scale_m / 2.
 
@@ -63,9 +62,11 @@ def quantize_uplink(g: jax.Array, uplink_dtype: str):
     if uplink_dtype == "bf16":
         return g.astype(jnp.bfloat16), None
     if uplink_dtype == "int8":
-        amax = jnp.max(jnp.abs(g.astype(jnp.float32)), axis=1)
+        amax = jnp.max(jnp.abs(g.astype(jnp.float32)),
+                       axis=tuple(range(1, g.ndim)))
         scale = jnp.maximum(amax, jnp.finfo(jnp.float32).tiny) / INT8_LEVELS
-        q = jnp.round(g.astype(jnp.float32) / scale[:, None])
+        q = jnp.round(g.astype(jnp.float32)
+                      / scale.reshape((-1,) + (1,) * (g.ndim - 1)))
         return jnp.clip(q, -INT8_LEVELS, INT8_LEVELS).astype(jnp.int8), scale
     raise ValueError(f"uplink_dtype must be one of {UPLINK_DTYPES}, "
                      f"got {uplink_dtype!r}")
@@ -79,52 +80,64 @@ def dequantize_uplink(wire: jax.Array, q_scale) -> jax.Array:
     return gf * q_scale[:, None].astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ota_aggregate(g: jax.Array, s: jax.Array, z: jax.Array,
-                  noise_scale: jax.Array, *, block_d: int = 64 * 1024,
+                  noise_scale: jax.Array, *,
                   interpret: Optional[bool] = None) -> jax.Array:
-    """Fused OTA aggregation over [N, D] gradients (see ota_aggregate.py)."""
-    interpret = _on_cpu() if interpret is None else interpret
-    gp, d0 = _pad_to(g, 1, 8 * 128)
-    zp, _ = _pad_to(z, 0, 8 * 128)
-    blk = min(block_d, gp.shape[1])
-    while gp.shape[1] % blk:
-        blk //= 2
-    out = oa.ota_aggregate_pallas(gp, s, zp,
-                                  jnp.asarray(noise_scale, gp.dtype),
-                                  block_d=blk, interpret=interpret)
-    return out[:d0]
+    """Fused OTA aggregation over [N, D] gradients: sum_m s_m g_m +
+    noise_scale * z, cast to g.dtype.
+
+    Runs the round-step kernel with params = 0 and eta = -1, whose output
+    ``0 - (-1) * ghat`` is ghat exactly — one kernel serves both the fused
+    round tail and the aggregate-only reference chain."""
+    d = g.shape[1]
+    out = ota_round_step(g, s, z, noise_scale, jnp.zeros((d,), jnp.float32),
+                         jnp.float32(-1.0), interpret=interpret)
+    return out.astype(g.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
+def _tiled(x: jax.Array, rows: int) -> jax.Array:
+    """Zero-pad the last axis of ``x`` to rows * LANES and view it as the
+    kernel's [rows, LANES] tiling."""
+    return jnp.reshape(_pad_to(x, x.ndim - 1, rows * rs.LANES),
+                       x.shape[:-1] + (rows, rs.LANES))
+
+
+@functools.partial(jax.jit, static_argnames=("uplink_dtype", "interpret"))
 def ota_round_step(g: jax.Array, s: jax.Array, z: jax.Array,
                    noise_scale: jax.Array, params: jax.Array,
-                   eta: jax.Array, q_scale=None, *,
-                   block_d: int = 64 * 1024,
+                   eta: jax.Array, *, uplink_dtype: str = "f32",
                    interpret: Optional[bool] = None) -> jax.Array:
-    """Fused OTA round step over [N, D] wire-dtype gradients + [D] params
-    (see round_step.py): dequantize, weighted-superpose, noise-inject and
-    SGD-update in one Pallas launch."""
+    """Fused OTA round step over [N, D] precoded gradients + [D] params
+    (see round_step.py): quantize for the uplink, dequantize, weighted-
+    superpose, noise-inject and SGD-update, the last four in one Pallas
+    launch.
+
+    Pads D to the kernel's [rows, LANES] tiling (``rs.tile_rows`` sizes
+    the blocks from the kernel's VMEM budget) and quantizes the gradient
+    stack already tiled (``quantize_uplink``), so a narrow wire is written
+    once, in place; re-tiling an int8 or bf16 [N, D] stack would be one
+    more copy, and one that XLA's TPU compiler takes minutes to compile
+    under the fleet's vmap.  Zero padding moves no int8 scale."""
     interpret = _on_cpu() if interpret is None else interpret
-    gp, d0 = _pad_to(g, 1, 8 * 128)
-    zp, _ = _pad_to(z, 0, 8 * 128)
-    pp, _ = _pad_to(params, 0, 8 * 128)
-    blk = min(block_d, gp.shape[1])
-    while gp.shape[1] % blk:
-        blk //= 2
-    qs = jnp.ones_like(s, jnp.float32) if q_scale is None \
+    n, d = g.shape
+    wire_bytes = min(jnp.dtype(g.dtype).itemsize,
+                     UPLINK_WIRE_BYTES.get(uplink_dtype, 4))
+    rows, block_rows = rs.tile_rows(n, d, wire_bytes)
+    wire, q_scale = quantize_uplink(_tiled(g, rows), uplink_dtype)
+    qs = jnp.ones((n,), jnp.float32) if q_scale is None \
         else q_scale.astype(jnp.float32)
-    out = rs.ota_round_step_pallas(gp, qs, s, zp,
-                                   jnp.asarray(noise_scale, jnp.float32),
-                                   pp, jnp.asarray(eta, jnp.float32),
-                                   block_d=blk, interpret=interpret)
-    return out[:d0]
+    coef = jnp.concatenate([jnp.asarray(noise_scale, jnp.float32).reshape(1),
+                            jnp.asarray(eta, jnp.float32).reshape(1),
+                            s.astype(jnp.float32), qs])[None]
+    out = rs.ota_round_step_pallas(wire, coef, _tiled(z, rows),
+                                   _tiled(params, rows),
+                                   block_rows=block_rows, interpret=interpret)
+    return out.reshape(-1)[:d]
 
 
 def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
                           key: jax.Array, params, eta, *,
                           uplink_dtype: str = "f32",
-                          block_d: int = 64 * 1024,
                           use_kernel: Optional[bool] = None,
                           interpret: Optional[bool] = None):
     """The whole flat-path round body — quantized uplink, OTA aggregation,
@@ -148,7 +161,8 @@ def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
     Dispatch follows ``ota_aggregate_pytree`` exactly: TPU → Pallas kernel;
     CPU → the pure-jnp flattened oracle ``ref.ota_round_step_ref``
     (interpret mode only when ``use_kernel=True`` is forced, as the
-    equivalence tests do).
+    equivalence tests do); both quantize the same values
+    (``ota_round_step`` does it in the kernel's tiling).
     """
     from repro.kernels import ref
 
@@ -166,15 +180,15 @@ def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
                          for k, sz in zip(keys, sizes)]).astype(dtype)
     p_flat = jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
                               for l in p_leaves])
-    wire, q_scale = quantize_uplink(g, uplink_dtype)
     ns = jnp.asarray(noise_scale, dtype)
     eta32 = jnp.asarray(eta, jnp.float32)
     if use_kernel is None:
         use_kernel = not _on_cpu()
     if use_kernel:
-        out = ota_round_step(wire, s, z, ns, p_flat, eta32, q_scale,
-                             block_d=block_d, interpret=interpret)
+        out = ota_round_step(g, s, z, ns, p_flat, eta32,
+                             uplink_dtype=uplink_dtype, interpret=interpret)
     else:
+        wire, q_scale = quantize_uplink(g, uplink_dtype)
         out = ref.ota_round_step_ref(wire, s, z, ns, p_flat, eta32,
                                      q_scale=q_scale)
     offsets = np.cumsum([0] + sizes)
@@ -185,7 +199,6 @@ def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
 
 def ota_aggregate_pytree(stacked: jax.Array, s: jax.Array, noise_scale,
                          key: jax.Array, *, uplink_dtype: str = "f32",
-                         block_d: int = 64 * 1024,
                          use_kernel: Optional[bool] = None,
                          interpret: Optional[bool] = None):
     """Fused OTA aggregation over a whole gradient *pytree* in one launch.
@@ -236,8 +249,7 @@ def ota_aggregate_pytree(stacked: jax.Array, s: jax.Array, noise_scale,
     if use_kernel is None:
         use_kernel = not _on_cpu()
     if use_kernel:
-        out = ota_aggregate(g, s, z, noise_scale, block_d=block_d,
-                            interpret=interpret)
+        out = ota_aggregate(g, s, z, noise_scale, interpret=interpret)
     else:
         out = ref.ota_aggregate_ref(g, s, z,
                                     jnp.asarray(noise_scale, dtype))
@@ -245,42 +257,3 @@ def ota_aggregate_pytree(stacked: jax.Array, s: jax.Array, noise_scale,
     parts = [out[offsets[i]:offsets[i + 1]].reshape(l.shape[1:]).astype(
         l.dtype) for i, l in enumerate(leaves)]
     return jax.tree.unflatten(treedef, parts)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "window", "block_q", "block_k",
-                                    "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Blocked attention [B,Sq,H,Dh] x [B,Sk,KH,Dh] -> [B,Sq,H,Dh]."""
-    interpret = _on_cpu() if interpret is None else interpret
-    sq, sk = q.shape[1], k.shape[1]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    qp, sq0 = _pad_to(q, 1, bq)
-    kp, _ = _pad_to(k, 1, bk)
-    vp, _ = _pad_to(v, 1, bk)
-    if not causal and kp.shape[1] != sk:
-        raise ValueError("non-causal attention requires Sk % block_k == 0 "
-                         "(padded keys would be attended)")
-    # padded k positions are masked out by causal (they sit in the future)
-    out = fa.flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                    block_q=bq, block_k=bk,
-                                    interpret=interpret)
-    return out[:, :sq0]
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x: jax.Array, dt: jax.Array, a_neg: jax.Array,
-             b_mat: jax.Array, c_mat: jax.Array, *, chunk: int = 128,
-             interpret: Optional[bool] = None) -> jax.Array:
-    """Mamba-2 SSD scan [B,S,H,P] -> [B,S,H,P] (see ssd_scan.py)."""
-    interpret = _on_cpu() if interpret is None else interpret
-    s = x.shape[1]
-    ch = min(chunk, s)
-    while s % ch:
-        ch //= 2
-    return ss.ssd_scan_pallas(x, dt, a_neg, b_mat, c_mat, chunk=ch,
-                              interpret=interpret)

@@ -6,9 +6,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-NEG_INF = -1e30
-
-
 def ota_aggregate_ref(g: jax.Array, s: jax.Array, z: jax.Array,
                       noise_scale: jax.Array) -> jax.Array:
     """out = sum_m s_m g_m + noise_scale * z  (g: [N, D]).
@@ -47,29 +44,6 @@ def ota_round_step_ref(g: jax.Array, s: jax.Array, z: jax.Array,
     ghat = acc + noise_scale.astype(jnp.float32) * z.astype(jnp.float32)
     return (params.astype(jnp.float32)
             - eta.astype(jnp.float32) * ghat).astype(params.dtype)
-
-
-def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  causal: bool = True,
-                  window: Optional[int] = None) -> jax.Array:
-    """Naive full-score GQA attention. q: [B,Sq,H,Dh]; k,v: [B,Sk,KH,Dh]."""
-    b, sq, h, dh = q.shape
-    _, sk, kh, _ = k.shape
-    g = h // kh
-    qg = q.reshape(b, sq, kh, g, dh).astype(jnp.float32)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k.astype(jnp.float32))
-    s = s / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    qpos = jnp.arange(sq)
-    kpos = jnp.arange(sk)
-    mask = jnp.ones((sq, sk), bool)
-    if causal:
-        mask &= kpos[None, :] <= qpos[:, None]
-    if window is not None:
-        mask &= kpos[None, :] > (qpos[:, None] - window)
-    s = jnp.where(mask[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(b, sq, h, dh).astype(q.dtype)
 
 
 def ssd_ref(x: jax.Array, dt: jax.Array, a_neg: jax.Array, b_mat: jax.Array,

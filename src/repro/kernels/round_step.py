@@ -4,7 +4,7 @@ The per-round hot path of the flat aggregation mode used to execute as a
 chain of four XLA ops — weighted OTA superposition, noise scaling, noise
 injection, SGD parameter update — each making its own pass over the [D]
 gradient vector.  This kernel fuses the whole post-gradient round body
-into ONE launch (ROADMAP "raw-speed pass"):
+into ONE launch:
 
     ghat[d]  = sum_m qs[m] * g[m, d] * s[m] + noise_scale * z[d]
     out[d]   = params[d] - eta * ghat[d]
@@ -16,14 +16,23 @@ the round operands (all-ones for f32/bf16 uplinks — the cast alone
 dequantizes those).  Everything accumulates in f32 regardless of the wire
 dtype; the output is cast to the params dtype on write.
 
-TPU-native design (DESIGN.md §Kernels): identical tiling to
-``ota_aggregate`` — the gradient axis in lane-aligned VMEM blocks
-(multiples of 8*128), the small client axis N (10..32) entirely inside
-each block, per-device scalars in (1, N)-blocked SMEM-ish specs — but one
-HBM round-trip instead of four: per tile the kernel reads the g block, a
-z block and a params block and writes one params block, so the op stays
-on the HBM-bandwidth roofline it was already bound by while moving ~2x
-fewer bytes than the unfused chain (which materializes ghat between ops).
+Tile layout (DESIGN.md §Kernels).  The gradient axis is folded into
+lane-dense 2-D tiles: D is padded to ``rows * LANES`` and every [D] operand
+is viewed as [rows, LANES], the [N, D] uplink as [N, rows, LANES].  A grid
+step owns ``block_rows`` rows (a multiple of ``ROW_ALIGN``, the int8
+sublane tile, so one layout serves f32, bf16 and int8 wires).  Every
+block's last two dims are (block_rows, LANES) — legal on the TPU tiling
+whatever leading axes ``jax.vmap`` prepends, which is how the fleet calls
+the kernel (one batch axis per grid level: [K] cells, or [K, S]).  The
+per-device scalars and (noise_scale, eta) ride one small f32 vector in
+SMEM.  The client axis is a loop inside the step, so the only f32
+temporaries are [block_rows, LANES] accumulators, never [N, block].
+
+Block size comes from a VMEM budget (``tile_rows``): N rows of the wire
+block double-buffered, the z / params / out blocks double-buffered, and
+the f32 temporaries must fit ``VMEM_BUDGET``.  D is padded up to a whole
+number of blocks, so large cohorts (N=50) get shorter blocks instead of a
+compile-time VMEM overflow.
 
 Validated on CPU with interpret=True against ref.ota_round_step_ref.
 """
@@ -32,51 +41,77 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
-SUBLANE = 8
-DEFAULT_BLOCK_D = 64 * 1024          # elements per tile (256 KB f32)
+LANES = 512                    # lane width of the 2-D view (4 x 128)
+ROW_ALIGN = 32                 # int8 sublane tile; also covers bf16 / f32
+VMEM_BUDGET = 8 * 1024 * 1024  # bytes per grid step, buffers + temporaries
+_F32_TEMPS = 4                 # acc, one dequantized row, z and params in f32
 
 
-def _kernel(s_ref, qs_ref, g_ref, z_ref, ns_ref, p_ref, eta_ref, out_ref):
-    # g_ref: [N, BD]; s_ref/qs_ref: [1, N]; z_ref/p_ref/out_ref: [BD]
-    s = s_ref[0, :].astype(jnp.float32)                    # [N]
-    qs = qs_ref[0, :].astype(jnp.float32)                  # [N] dequant scale
-    g = g_ref[...].astype(jnp.float32) * qs[:, None]       # dequantized [N,BD]
-    acc = jnp.sum(g * s[:, None], axis=0)
-    ghat = acc + ns_ref[0].astype(jnp.float32) * z_ref[...].astype(jnp.float32)
-    upd = p_ref[...].astype(jnp.float32) \
-        - eta_ref[0].astype(jnp.float32) * ghat
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def tile_rows(n: int, d: int, wire_bytes: int) -> tuple:
+    """(rows, block_rows) of the [rows, LANES] view of a length-``d``
+    vector for an ``n``-device uplink of ``wire_bytes`` per element.
+
+    Per element of a block a grid step holds: the n-row wire block twice
+    (double-buffered), z / params in and out twice at 4 bytes, and
+    ``_F32_TEMPS`` f32 temporaries.  The largest ROW_ALIGN-multiple of rows
+    that fits ``VMEM_BUDGET`` bounds the block; the block count is then
+    fixed and the rows re-balanced across blocks, so padding stays under one
+    ROW_ALIGN stripe per block instead of up to a whole block."""
+    per_elem = 2 * n * wire_bytes + 2 * 3 * 4 + _F32_TEMPS * 4
+    max_rows = max(ROW_ALIGN,
+                   VMEM_BUDGET // (per_elem * LANES) // ROW_ALIGN * ROW_ALIGN)
+    need = _cdiv(d, LANES)
+    blocks = _cdiv(need, max_rows)
+    block_rows = _cdiv(_cdiv(need, blocks), ROW_ALIGN) * ROW_ALIGN
+    return blocks * block_rows, block_rows
+
+
+def _kernel(coef_ref, g_ref, z_ref, p_ref, out_ref):
+    # coef_ref: SMEM f32 [1, 2 + 2N] = (noise_scale, eta, s[0:N], qs[0:N])
+    # g_ref: [N, BR, LANES] wire dtype; z_ref / p_ref / out_ref: [BR, LANES]
+    n = g_ref.shape[0]
+
+    def device(m, acc):
+        g = g_ref[m].astype(jnp.float32) * coef_ref[0, 2 + n + m]   # dequant
+        return acc + g * coef_ref[0, 2 + m]
+
+    acc = jax.lax.fori_loop(0, n, device,
+                            jnp.zeros(out_ref.shape, jnp.float32))
+    ghat = acc + coef_ref[0, 0] * z_ref[...].astype(jnp.float32)
+    upd = p_ref[...].astype(jnp.float32) - coef_ref[0, 1] * ghat
     out_ref[...] = upd.astype(out_ref.dtype)
 
 
-def ota_round_step_pallas(g: jax.Array, qs: jax.Array, s: jax.Array,
-                          z: jax.Array, noise_scale: jax.Array,
-                          params: jax.Array, eta: jax.Array, *,
-                          block_d: int = DEFAULT_BLOCK_D,
+def ota_round_step_pallas(g: jax.Array, coef: jax.Array, z: jax.Array,
+                          params: jax.Array, *, block_rows: int,
                           interpret: bool = False) -> jax.Array:
-    """g: [N, D] (D a multiple of 8*128 after padding by ops.py, any wire
-    dtype incl. int8/bf16); qs/s: [N]; z/params: [D]; noise_scale/eta:
-    scalars.  Returns the updated [D] params in params.dtype."""
-    n, d = g.shape
-    block_d = min(block_d, d)
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
-
+    """g: [N, rows, LANES] (any wire dtype incl. int8/bf16); coef: f32
+    [1, 2 + 2N] = (noise_scale, eta, s, qs) — 2-D so that its block stays
+    the whole trailing array under vmap; z / params: [rows, LANES] with
+    rows a multiple of ``block_rows`` (``tile_rows`` picks both).  Returns
+    the updated [rows, LANES] params in params.dtype."""
+    n, rows, lanes = g.shape
+    if rows % block_rows or block_rows % ROW_ALIGN or lanes != LANES:
+        raise ValueError(f"tile {g.shape} / block_rows={block_rows} is not "
+                         f"a [N, k*block_rows, {LANES}] layout")
+    tile = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(rows // block_rows,),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),          # s (broadcast)
-            pl.BlockSpec((1, n), lambda i: (0, 0)),          # qs (broadcast)
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),    # g tile
-            pl.BlockSpec((block_d,), lambda i: (i,)),        # z tile
-            pl.BlockSpec((1,), lambda i: (0,)),              # noise_scale
-            pl.BlockSpec((block_d,), lambda i: (i,)),        # params tile
-            pl.BlockSpec((1,), lambda i: (0,)),              # eta
+            pl.BlockSpec(memory_space=pltpu.SMEM),                # coef
+            pl.BlockSpec((n, block_rows, LANES), lambda i: (0, i, 0)),
+            tile,                                                 # z
+            tile,                                                 # params
         ],
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((d,), params.dtype),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), params.dtype),
+        name="ota_round_step",
         interpret=interpret,
-    )(s.reshape(1, n), qs.reshape(1, n), g, z, noise_scale.reshape(1),
-      params, eta.reshape(1))
+    )(coef, g, z, params)

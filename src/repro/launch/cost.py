@@ -48,8 +48,8 @@ def _direct_attention():
 
 
 def _cost_of(compiled) -> dict:
-    from repro.launch.hlo import collective_bytes, cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    from repro.launch.hlo import collective_bytes
+    cost = compiled.cost_analysis() or {}
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),

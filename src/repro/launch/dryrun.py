@@ -32,7 +32,7 @@ from repro.launch import steps as steps_lib
 from repro.models.param import param_bytes, param_count
 from repro.models.registry import build_bundle
 
-from repro.launch.hlo import collective_bytes, cost_analysis_dict  # noqa: E402
+from repro.launch.hlo import collective_bytes  # noqa: E402
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                             "experiments", "dryrun")
@@ -113,7 +113,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         }
     except Exception as e:  # CPU backend may not implement it
         mem_info = {"error": str(e)}
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 
@@ -124,6 +124,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         "mesh": dict(zip(mesh.axis_names, [int(mesh.shape[a])
                                            for a in mesh.axis_names])),
         "devices": int(n_dev),
+        "device_kind": mesh_lib.TARGET_DEVICE_KIND,
         "scheme": scheme,
         "num_params": int(bundle.num_params),
         "param_bytes_total": int(param_bytes(bundle.defs)),

@@ -24,16 +24,6 @@ _COLL_RE = re.compile(
     r"\(")
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element list of per-program dicts, newer ones a
-    plain dict.  Always returns a dict (possibly empty)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 def shape_bytes(type_str: str) -> int:
     total = 0
     for dtype, dims in _SHAPE_RE.findall(type_str):

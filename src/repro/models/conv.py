@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.models.mlp import top1_accuracy
 from repro.models.param import ParamDef, param_count
 
 INPUT_SHAPE = (32, 32, 3)
@@ -93,5 +94,4 @@ def conv_loss(params, batch, l2: float = L2_COEF):
 
 
 def accuracy(params, x, y):
-    logits = conv_forward(params, x)
-    return jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+    return top1_accuracy(conv_forward(params, x), y)

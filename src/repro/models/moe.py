@@ -126,18 +126,20 @@ def moe_apply(p, x: jax.Array, cfg: ModelConfig):
         bspec = P(baxes if baxes else None)
         rep = P(*([None] * 2))
 
-        dispatch = dist.shard_map(
+        dispatch = jax.shard_map(
             jax.vmap(dispatch_one),
             mesh=mesh,
             in_specs=(P(bspec[0], None, None), P(bspec[0], None, None)),
             out_specs=(P(bspec[0], None, None, None),
-                       P(bspec[0], None), P(bspec[0], None)))
-        combine = dist.shard_map(
+                       P(bspec[0], None), P(bspec[0], None)),
+            check_vma=False)
+        combine = jax.shard_map(
             jax.vmap(combine_one),
             mesh=mesh,
             in_specs=(P(bspec[0], None, None, None), P(bspec[0], None, None),
                       P(bspec[0], None), P(bspec[0], None)),
-            out_specs=P(bspec[0], None, None))
+            out_specs=P(bspec[0], None, None),
+            check_vma=False)
         ein, slot, keep = dispatch(x, top_e)
     else:
         ein, slot, keep = jax.vmap(dispatch_one)(x, top_e)

@@ -10,11 +10,11 @@
 """
 from repro.solvers.sca_jax import (BatchResult, DEFAULT_CONFIG, SolverConfig,
                                    set_trace_hook, solve, solve_batch,
-                                   solve_batch_device)
+                                   solve_batch_device, x64_scope)
 from repro.solvers.theory_jax import SolverParams, from_ota, stack_params
 
 __all__ = [
     "BatchResult", "DEFAULT_CONFIG", "SolverConfig", "SolverParams",
     "from_ota", "set_trace_hook", "solve", "solve_batch",
-    "solve_batch_device", "stack_params",
+    "solve_batch_device", "stack_params", "x64_scope",
 ]

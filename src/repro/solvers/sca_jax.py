@@ -32,13 +32,20 @@ Structure (DESIGN.md §Solvers):
   ~1e-6 relative on the reference cases (asserted in tests and
   benchmarks/sca_bench.py).
 
-Everything runs under ``jax.experimental.enable_x64``: the *scaled*
+Everything runs under ``jax.enable_x64(True)``: the *scaled*
 variables are O(1) but intermediate quantities (alpha ~ 1e-8, alpha^2 in
 the noise term) need f64 headroom.  The x64 scope is entered per public
 call and never leaks into the (f32) training path.
+
+Where the default backend is an accelerator, ``x64_scope`` also pins the
+solve to the host's CPU backend: a TPU emulates f64, and each new solve
+program there compiles and runs for ~100 s where the host takes seconds.
+An explicit ``placement`` in ``solve_batch`` still maps the batch onto its
+own devices.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -47,7 +54,6 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.sca import SCAResult
 from repro.core.theory import OTAParams
@@ -345,6 +351,18 @@ def _placed_batch_solver(placement, cfg):
 # public API
 # ---------------------------------------------------------------------------
 
+@contextlib.contextmanager
+def x64_scope():
+    """The scope every design solve runs in: x64 on, and the host's CPU
+    backend as default device when the default backend is not the CPU."""
+    with jax.enable_x64(True):
+        if jax.default_backend() == "cpu":
+            yield
+        else:
+            with jax.default_device(jax.devices("cpu")[0]):
+                yield
+
+
 def solve(prm: OTAParams, gamma0: Optional[np.ndarray] = None,
           cfg: SolverConfig = DEFAULT_CONFIG) -> SCAResult:
     """Single-scenario compiled SCA solve; drop-in for ``sca.solve_sca``.
@@ -352,7 +370,7 @@ def solve(prm: OTAParams, gamma0: Optional[np.ndarray] = None,
     Returns the same ``SCAResult`` (numpy, physical units); ``iterations``
     reports the fixed outer budget (the loop is compiled, not early-exited).
     """
-    with enable_x64():
+    with x64_scope():
         pj = tj.from_ota(prm)
         g0 = None if gamma0 is None else jnp.asarray(gamma0, jnp.float64)
         out = _solve_single_jit(pj, g0, cfg, gamma0 is not None)
@@ -387,9 +405,10 @@ def solve_batch(prms, cfg: SolverConfig = DEFAULT_CONFIG,
     design batch over the ``("data", "model")`` mesh exactly like the
     fleet grid shards (rows are independent; the shard_map is psum-free,
     with the same pad-with-row-0 rule when B doesn't divide the device
-    count).  ``None`` (default) keeps the single-device vmap program.
+    count).  ``None`` (default) keeps the single-device vmap program, on
+    the host (``x64_scope``).
     """
-    with enable_x64():
+    with (x64_scope() if placement is None else jax.enable_x64(True)):
         pj = _as_f64(prms if isinstance(prms, SolverParams) else stack(prms))
         if placement is None:
             out = _solve_batch_jit(pj, cfg)
@@ -429,10 +448,10 @@ def solve_batch_device(prm_b: SolverParams,
 
     Used by the in-training re-design path (``AdaptiveSCA``), where the
     batch of scenarios is derived from the live fading state.  Caller is
-    responsible for the x64 scope semantics: this enters it too, so the
-    returned arrays are f64.
+    responsible for the x64 scope semantics: this enters ``x64_scope`` too,
+    so the returned arrays are f64 (on the host, off the CPU backend).
     """
-    with enable_x64():
+    with x64_scope():
         hook = _TRACE_HOOK
         t0 = time.monotonic() if hook is not None else 0.0
         out = _solve_batch_jit(_as_f64(prm_b), cfg)

@@ -22,7 +22,7 @@ engine uses (the Poisson(a^2/2 = K) tail at ``_MARCUM_TERMS`` is
 negligible for K <~ 40).
 
 All functions follow input dtype; the public solver entry points run them
-under ``jax.experimental.enable_x64`` because the physical scales
+under ``jax.enable_x64(True)`` because the physical scales
 (gains ~1e-9..1e-13, N0 ~1e-21) need f64 headroom even though the *scaled*
 SCA variables are O(1).
 """

@@ -21,7 +21,7 @@ SCRIPT = textwrap.dedent("""
     import json, jax, jax.numpy as jnp
     from repro import configs, distributed as dist
     from repro.launch import mesh as mesh_lib, steps as steps_lib
-    from repro.launch.hlo import collective_bytes, cost_analysis_dict
+    from repro.launch.hlo import collective_bytes
     from repro.launch.dryrun import _scheme_for
     from repro.models.registry import build_bundle
     from repro.configs.shapes import InputShape
@@ -47,7 +47,7 @@ SCRIPT = textwrap.dedent("""
                 step = steps_lib.make_serve_step(bundle)
             jitted = jax.jit(step, in_shardings=(pshard,) + tuple(shardings))
             compiled = jitted.lower(bundle.abstract(), *args).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         coll = collective_bytes(compiled.as_text())
         mem = compiled.memory_analysis()
         results[arch + ":" + kind] = {
@@ -76,7 +76,7 @@ def test_debug_mesh_dryrun_all_kinds():
 
 
 def test_collective_bytes_parser():
-    from repro.launch.hlo import collective_bytes, cost_analysis_dict
+    from repro.launch.hlo import collective_bytes
     hlo = """
       %ar = bf16[1024,32]{1,0} all-reduce(bf16[1024,32] %x), replica_groups={}
       %ag.1 = f32[64]{0} all-gather(f32[16] %y), dimensions={0}

@@ -71,35 +71,54 @@ def test_ota_aggregate_property(n, d, seed):
 # ota_round_step (fused round tail: dequant + aggregate + noise + SGD step)
 # ---------------------------------------------------------------------------
 
-def _round_operands(n, d, seed=0, wire=jnp.float32):
+_UPLINK_OF = {jnp.float32: "f32", jnp.bfloat16: "bf16", jnp.int8: "int8"}
+
+
+def _round_operands(n, d, seed=0):
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(seed), 4)
     g = jax.random.normal(k1, (n, d), jnp.float32)
-    q_scale = None
-    if wire == jnp.int8:
-        g, q_scale = ops.quantize_uplink(g, "int8")
-    elif wire != jnp.float32:
-        g = g.astype(wire)
     s = jax.random.uniform(k2, (n,), jnp.float32)
     z = jax.random.normal(k3, (d,), jnp.float32)
     p = jax.random.normal(k4, (d,), jnp.float32)
-    return g, s, z, p, q_scale
+    return g, s, z, p
 
 
 @pytest.mark.parametrize("n", [1, 10])
-@pytest.mark.parametrize("d", [128, 1024, 5000])       # 5000: non-aligned
+# 5000: non-aligned; 100_003: several grid steps at N=10
+@pytest.mark.parametrize("d", [128, 1024, 5000, 100_003])
 @pytest.mark.parametrize("wire", [jnp.float32, jnp.bfloat16, jnp.int8])
 def test_ota_round_step_kernel_vs_ref(n, d, wire):
     """Interpret-mode Pallas kernel vs the flat jnp oracle, including the
-    lane-padding edge (d=5000 is not a multiple of 8*128: padded g/z/params
-    columns must never leak into the first d outputs)."""
-    g, s, z, p, q_scale = _round_operands(n, d, wire=wire)
+    padding edge (d=5000 and 100_003 do not fill whole [rows, LANES] blocks:
+    padded g/z/params elements must never leak into the first d outputs)."""
+    g, s, z, p = _round_operands(n, d)
     ns, eta = jnp.float32(0.25), jnp.float32(0.05)
-    out = ops.ota_round_step(g, s, z, ns, p, eta, q_scale,
-                             interpret=True)
-    exp = ref.ota_round_step_ref(g, s, z, ns, p, eta, q_scale=q_scale)
+    out = ops.ota_round_step(g, s, z, ns, p, eta,
+                             uplink_dtype=_UPLINK_OF[wire], interpret=True)
+    w, q_scale = ops.quantize_uplink(g, _UPLINK_OF[wire])
+    exp = ref.ota_round_step_ref(w, s, z, ns, p, eta, q_scale=q_scale)
     assert out.shape == (d,)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("n", [1, 10, 50, 200])
+@pytest.mark.parametrize("wire_bytes", [4, 2, 1])
+def test_tile_rows_fit_the_vmem_budget(n, wire_bytes):
+    """The block layout at the paper's width: whole 32-row blocks that
+    cover d with under one 32-row stripe of padding per block, and a grid
+    step's buffers and f32 temporaries within the VMEM budget."""
+    from repro.kernels import round_step as rs
+
+    d = 814_090
+    rows, block_rows = rs.tile_rows(n, d, wire_bytes)
+    blocks = rows // block_rows
+    assert rows % block_rows == 0 and block_rows % rs.ROW_ALIGN == 0
+    assert rows * rs.LANES >= d
+    assert rows - (-(-d // rs.LANES)) < blocks * rs.ROW_ALIGN
+    per_elem = 2 * n * wire_bytes + 2 * 3 * 4 + 4 * 4
+    assert (block_rows == rs.ROW_ALIGN
+            or block_rows * rs.LANES * per_elem <= rs.VMEM_BUDGET)
 
 
 def _tree_oracle(grads, params, s, ns, k_noise, eta):
@@ -271,83 +290,8 @@ def test_run_fleet_f32_fused_bitwise_parity():
 
 
 # ---------------------------------------------------------------------------
-# flash_attention
+# models/ssm.py chunked SSD vs the sequential oracle
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("sq,sk", [(128, 128), (256, 256), (64, 256),
-                                   (1, 512), (100, 100)])
-@pytest.mark.parametrize("h,kh", [(4, 4), (4, 2), (8, 1)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(sq, sk, h, kh, dtype):
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    dh = 64
-    q = jax.random.normal(k1, (2, sq, h, dh), dtype)
-    k = jax.random.normal(k2, (2, sk, kh, dh), dtype)
-    v = jax.random.normal(k3, (2, sk, kh, dh), dtype)
-    out = ops.flash_attention(q, k, v, causal=True)
-    exp = ref.attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32), **_tol(dtype))
-
-
-@pytest.mark.parametrize("window", [16, 64, 128])
-def test_flash_attention_window(window):
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    q = jax.random.normal(k1, (1, 256, 4, 32))
-    k = jax.random.normal(k2, (1, 256, 2, 32))
-    v = jax.random.normal(k3, (1, 256, 2, 32))
-    out = ops.flash_attention(q, k, v, causal=True, window=window)
-    exp = ref.attention_ref(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_attention_noncausal():
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    q = jax.random.normal(k1, (1, 128, 2, 32))
-    k = jax.random.normal(k2, (1, 128, 2, 32))
-    v = jax.random.normal(k3, (1, 128, 2, 32))
-    out = ops.flash_attention(q, k, v, causal=False)
-    exp = ref.attention_ref(q, k, v, causal=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=2e-5, atol=2e-5)
-
-
-@settings(max_examples=15, deadline=None)
-@given(st.integers(1, 3), st.sampled_from([32, 64, 128]),
-       st.sampled_from([1, 2, 4]), st.integers(0, 2**31 - 1))
-def test_flash_attention_property(b, s, kh, seed):
-    kk = jax.random.PRNGKey(seed)
-    k1, k2, k3 = jax.random.split(kk, 3)
-    h, dh = kh * 2, 32
-    q = jax.random.normal(k1, (b, s, h, dh))
-    k = jax.random.normal(k2, (b, s, kh, dh))
-    v = jax.random.normal(k3, (b, s, kh, dh))
-    out = ops.flash_attention(q, k, v, causal=True)
-    exp = ref.attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=3e-5, atol=3e-5)
-
-
-# ---------------------------------------------------------------------------
-# ssd_scan
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("s,chunk", [(64, 16), (128, 128), (96, 32)])
-@pytest.mark.parametrize("h,g", [(4, 1), (4, 2), (8, 8)])
-def test_ssd_scan_sweep(s, chunk, h, g):
-    k1, k2, k3, k4 = jax.random.split(KEY, 4)
-    b, p, n = 2, 16, 16
-    x = jax.random.normal(k1, (b, s, h, p))
-    dt = jax.nn.softplus(jax.random.normal(k2, (b, s, h)))
-    a_neg = -jnp.exp(jax.random.normal(k3, (h,)) * 0.5)
-    bm = jax.random.normal(k4, (b, s, g, n)) * 0.5
-    cm = jax.random.normal(k1, (b, s, g, n)) * 0.5
-    out = ops.ssd_scan(x, dt, a_neg, bm, cm, chunk=chunk)
-    exp = ref.ssd_ref(x, dt, a_neg, bm, cm)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=2e-4, atol=2e-4)
-
 
 def test_ssd_model_path_matches_ref():
     """models/ssm.ssd_chunked (the production path) == sequential oracle."""

@@ -22,7 +22,6 @@ except ImportError:
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro import solvers
 from repro.core import channel, sca, theory
@@ -58,7 +57,7 @@ def _random_prm(seed, n, family):
 
 def _check_theory_parity(seed, n, family):
     prm = _random_prm(seed, n, family)
-    with enable_x64():
+    with jax.enable_x64(True):
         pj = tj.from_ota(prm)
         gm_np = theory.gamma_max(prm)
         gm_j = np.asarray(tj.gamma_max(pj))
@@ -94,7 +93,7 @@ if HAVE_HYPOTHESIS:
 @pytest.mark.parametrize("family", ["rayleigh", "rician", "nakagami"])
 def test_theory_parity_with_dropout(family):
     prm = _random_prm(3, 8, family).replace(dropout=0.15)
-    with enable_x64():
+    with jax.enable_x64(True):
         pj = tj.from_ota(prm)
         gm = theory.gamma_max(prm)
         assert _rel(np.asarray(tj.alpha_max(pj)), theory.alpha_max(prm)) < 1e-6
@@ -107,7 +106,7 @@ def test_theory_parity_with_dropout(family):
 
 def test_marcum_q1_matches_scipy_rice():
     from scipy.stats import rice
-    with enable_x64():
+    with jax.enable_x64(True):
         a = jnp.asarray([0.0, 0.3, 1.0, 3.0, 7.0], jnp.float64)[:, None]
         b = jnp.asarray([0.1, 0.5, 1.0, 2.0, 5.0], jnp.float64)[None, :]
         q = np.asarray(tj.marcum_q1(jnp.broadcast_to(a, (5, 5)),
@@ -269,7 +268,7 @@ def test_solve_batch_accepts_prestacked_f32_params():
     """stack_params outside an x64 scope yields f32 leaves; solve_batch
     must recast instead of crashing the scan carry dtype check."""
     prms = [_random_prm(s, 6, "rayleigh") for s in range(3)]
-    stacked = tj.stack_params(prms)       # built OUTSIDE enable_x64
+    stacked = tj.stack_params(prms)       # built OUTSIDE jax.enable_x64
     br = solvers.solve_batch(stacked)
     ref = solvers.solve_batch(prms)
     np.testing.assert_allclose(br.objective, ref.objective, rtol=1e-6)

@@ -268,3 +268,17 @@ def test_cifar_conv_sharded_matches_vmap(cifar_world):
                for a, b in zip(jax.tree.leaves(res_v.params),
                                jax.tree.leaves(res_s.params)))
     assert diff < 1e-5, diff
+
+
+@pytest.mark.parametrize("num_classes", [2, 10])
+def test_top1_accuracy_is_argmax_accuracy(num_classes):
+    """The image tasks' accuracy (max-and-compare) equals the argmax rule
+    on every row, ties included: integer logits in a narrow range tie
+    often, and argmax then takes the lowest class."""
+    kl, ky = jax.random.split(jax.random.PRNGKey(3))
+    logits = jax.random.randint(kl, (4, 500, num_classes), -2, 3
+                                ).astype(jnp.float32)
+    y = jax.random.randint(ky, (4, 500), 0, num_classes)
+    got = jax.vmap(mlp.top1_accuracy)(logits, y)
+    want = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32), -1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
