@@ -161,9 +161,9 @@ def _recording_placement():
         texts: list = dataclasses.field(default_factory=list, compare=False)
 
         def build_chunk(self, round_body, adaptive, cohort=False,
-                        scenario=False, tracer=None):
+                        scenario=False):
             jitted = super().build_chunk(round_body, adaptive, cohort,
-                                         scenario, tracer=None)
+                                         scenario)
             programs = {}
 
             def chunk(*args, length):
@@ -176,6 +176,7 @@ def _recording_placement():
                     self.texts.append(programs[key].as_text())
                 return programs[key](*args)
 
+            chunk._cache_size = lambda: len(programs)
             return chunk
 
     return Recording()

@@ -31,6 +31,7 @@ needs no RNG cursor: a restart re-derives every draw from the chunk index.
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import os
 import time
@@ -206,6 +207,20 @@ def _load_fleet_state(path: str, stacked, params_b, fading_state, keys_b,
     return (int(meta["chunks_done"]), int(meta["rounds_done"]),
             state["scheme"], state["carry"]["params"], fstate,
             state["carry"]["keys"], metric_chunks, evals, designs, cohorts)
+
+
+def grid_eval(eval_fn: Callable):
+    """``eval_fn`` vmapped over the [K, S] cell grid and jitted, under the
+    ``fl.eval`` name scope (metadata only: the program is the bare
+    ``jax.jit(jax.vmap(jax.vmap(eval_fn)))``, under the same name)."""
+    per_cell = jax.vmap(jax.vmap(eval_fn))
+
+    @functools.wraps(per_cell)
+    def scoped(params_b):
+        with jax.named_scope("fl.eval"):
+            return per_cell(params_b)
+
+    return jax.jit(scoped)
 
 
 def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
@@ -400,8 +415,7 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                                  uplink_dtype=uplink_dtype,
                                  fuse_round=fuse_round)
     chunk = placement.build_chunk(round_body, adaptive or pop_adaptive,
-                                  cohort=pop_mode, scenario=scen_mode,
-                                  tracer=tracer)
+                                  cohort=pop_mode, scenario=scen_mode)
 
     data = tuple(jnp.asarray(a) for a in data)
     params_b = jax.tree.map(
@@ -429,9 +443,7 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
         # cohort states are staged per chunk from the re-entry table
         pop_table = population.init_table(s_axis)
 
-    eval_b = None
-    if eval_fn is not None:
-        eval_b = jax.jit(jax.vmap(jax.vmap(eval_fn)))
+    eval_b = None if eval_fn is None else grid_eval(eval_fn)
 
     designs = None
     if adaptive:
@@ -537,12 +549,13 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
     staged = next_fut = None
     wall_stage = 0.0
     stage_walls = [] if pop_mode else None
-    wall_compile, first = 0.0, True
-    prev_hook, hook_set = None, False
+    wall_compile = 0.0
+    prev_hook, prev_compile, hook_set = None, None, False
     if tracer is not None:
         from repro.solvers import sca_jax
         prev_hook = sca_jax.set_trace_hook(
             lambda rec: tracer.event("sca_solve", **rec))
+        prev_compile = tlm.set_compile_tracer(tracer)
         hook_set = True
     try:
         for ci, length in enumerate(lengths):
@@ -607,7 +620,8 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                     # overlap device execution instead of serializing
                     next_fut = executor.submit(_stage, ci + 1, stacked)
             with _ctx(chunk=ci):
-                t_ex = time.monotonic()
+                size0 = tlm.chunk_cache_size(chunk)
+                t_call, t_call_ns = time.monotonic(), time.time_ns()
                 if pop_mode:
                     params_b, fading_state, keys_b, metrics = chunk(
                         stacked, etas, params_b, fading_state, keys_b, data,
@@ -620,18 +634,34 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                     params_b, fading_state, keys_b, metrics = chunk(
                         stacked, etas, params_b, fading_state, keys_b, data,
                         length=length)
+                t_ret, t_ret_ns = time.monotonic(), time.time_ns()
+                size1 = tlm.chunk_cache_size(chunk)
+                t_ex, t_ex_ns = t_call, t_call_ns
+                if size0 is not None and size1 > size0:
+                    # a call that grows the compile cache traces, lowers
+                    # and compiles (or fetches) before it dispatches, and
+                    # dispatch is async: its wall is the compile, and the
+                    # chunk's execution starts where it returns
+                    wall_compile += t_ret - t_call
+                    t_ex, t_ex_ns = t_ret, t_ret_ns
+                    if tracer is not None:
+                        pad = getattr(chunk, "_pad_frac", None)
+                        frac = pad() if pad is not None else None
+                        extra = {} if frac is None \
+                            else {"padded_frac": round(frac, 6)}
+                        tracer.event("chunk_compile",
+                                     dur=round(t_ret - t_call, 6),
+                                     t0_ns=t_call_ns, t1_ns=t_ret_ns,
+                                     length=int(length), cache_size=size1,
+                                     **extra)
                 if tracer is not None:
                     # the block makes dur the true device wall (dispatch is
                     # async); telemetry-off keeps the async pipeline as-is
                     jax.block_until_ready(params_b)
                     tracer.event("chunk_exec", chunk=ci, length=int(length),
-                                 t_start=t,
-                                 cache_size=tlm.chunk_cache_size(chunk),
-                                 dur=round(time.monotonic() - t_ex, 6))
-            if first:
-                jax.block_until_ready(params_b)
-                wall_compile = time.time() - t0
-                first = False
+                                 t_start=t, cache_size=size1,
+                                 dur=round(time.monotonic() - t_ex, 6),
+                                 t0_ns=t_ex_ns)
             metric_chunks.append(metrics)
             t += length
             if pop_mode and fading is not None:
@@ -651,7 +681,7 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                                        np.asarray(fading_state))
                 designs.append((t, np.asarray(stacked.gamma)))
             if eval_b is not None:
-                with _span("eval", chunk=ci, t=t - 1):
+                with _ctx(chunk=ci), _span("eval", chunk=ci, t=t - 1):
                     ev = {kk: np.asarray(v)
                           for kk, v in eval_b(params_b).items()}
                 evals.append((t - 1, ev))
@@ -672,6 +702,7 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
     finally:
         if hook_set:
             sca_jax.set_trace_hook(prev_hook)
+            tlm.set_compile_tracer(prev_compile)
         if executor is not None:
             executor.shutdown(wait=True)
 

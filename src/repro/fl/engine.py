@@ -70,11 +70,13 @@ class FLResult:
     names         scheme names, length K (single runs: (scheme.name,))
     seeds         seeds swept, length S
     wall          total wall-clock seconds (= wall_compile + wall_exec)
-    wall_compile  seconds through the end of the FIRST chunk call — setup
-                  plus the dominant XLA compile; benchmark speedups quote
-                  it separately so compile never inflates throughput
-    wall_exec     seconds after the first chunk — steady-state execution
-                  (later chunk lengths may still add smaller compiles)
+    wall_compile  summed wall of the chunk calls that grew the chunk's
+                  compile cache: each traces, lowers and compiles (or
+                  fetches from the persistent cache) before it dispatches,
+                  so its wall is compile; benchmark speedups quote it
+                  separately so compile never inflates throughput
+    wall_exec     the rest: set-up, execution, eval and host work between
+                  chunks
     fading_state  final FadingProcess state (None on the i.i.d. path)
     designs       adaptive-scheme design trace: [(round, gamma [K, S, N])]
                   with entry (t, g) meaning design g is in effect from
@@ -195,6 +197,17 @@ def make_round_body(loss_fn: Callable, gains: np.ndarray, run,
         raise ValueError("scenario=True owns the channel process; "
                          "pass fading=None")
 
+    # the round's layers as name scopes (metadata only: the compiled
+    # program and its compile-cache key are unchanged), so a profile
+    # attributes device time to them: fl.grad (minibatch draw, per-device
+    # gradients, clip), fl.channel (fading, coefficients), fl.step (the
+    # aggregation and SGD update; the flat path carves its layout work out
+    # as fl.uplink, in kernels/ops.py)
+    def grads_of(params, data, k_batch):
+        with jax.named_scope("fl.grad"):
+            batch = sample(data, k_batch)
+            return jax.vmap(lambda b: device_grad(params, b))(batch)
+
     def device_grad(params, batch):
         g = jax.grad(loss_fn)(params, batch)
         if run.clip_to_gmax:
@@ -221,20 +234,23 @@ def make_round_body(loss_fn: Callable, gains: np.ndarray, run,
         # coefficients once, threaded into both the aggregation and the
         # metrics — they can never disagree (bbfl_alternative randomizes
         # round_coeffs, so recomputing from a different key split would).
-        k_coeff, k_noise = ota.split_ota_key(k_ota)
-        s, noise_scale = scheme.round_coeffs(h, k_coeff)
-        if fuse:
-            params = ota.fused_round_step(grads, s, noise_scale, k_noise,
-                                          params, eta,
-                                          uplink_dtype=uplink_dtype)
-        else:
-            g_hat = ota.apply_round_coeffs(grads, s, noise_scale, k_noise,
-                                           flat=flat,
-                                           uplink_dtype=uplink_dtype)
-            params = jax.tree.map(
-                lambda p, g: (p.astype(jnp.float32)
-                              - eta * g.astype(jnp.float32)).astype(p.dtype),
-                params, g_hat)
+        with jax.named_scope("fl.channel"):
+            k_coeff, k_noise = ota.split_ota_key(k_ota)
+            s, noise_scale = scheme.round_coeffs(h, k_coeff)
+        with jax.named_scope("fl.step"):
+            if fuse:
+                params = ota.fused_round_step(grads, s, noise_scale, k_noise,
+                                              params, eta,
+                                              uplink_dtype=uplink_dtype)
+            else:
+                g_hat = ota.apply_round_coeffs(grads, s, noise_scale,
+                                               k_noise, flat=flat,
+                                               uplink_dtype=uplink_dtype)
+                params = jax.tree.map(
+                    lambda p, g: (p.astype(jnp.float32)
+                                  - eta * g.astype(jnp.float32)
+                                  ).astype(p.dtype),
+                    params, g_hat)
         metrics = {
             "grad_norm_mean": jnp.mean(norms),
             "active_devices": jnp.sum((s > 0).astype(jnp.float32)),
@@ -247,34 +263,35 @@ def make_round_body(loss_fn: Callable, gains: np.ndarray, run,
 
     def body(scheme, eta, params, fading_state, key, data):
         k_fade, k_ota, k_batch = jax.random.split(key, 3)
-        batch = sample(data, k_batch)
-        grads, norms = jax.vmap(lambda b: device_grad(params, b))(batch)
-        if fading is None:
-            h = ota.draw_fading(k_fade, gains_j)
-        else:
-            fading_state, h = fading.step(fading_state, k_fade)
+        grads, norms = grads_of(params, data, k_batch)
+        with jax.named_scope("fl.channel"):
+            if fading is None:
+                h = ota.draw_fading(k_fade, gains_j)
+            else:
+                fading_state, h = fading.step(fading_state, k_fade)
         return finish(scheme, eta, params, fading_state, k_ota, h, grads,
                       norms)
 
     def cohort_body(scheme, eta, params, fading_state, key, data, co):
         k_fade, k_ota, k_batch = jax.random.split(key, 3)
-        active = jax.tree.map(lambda a: jnp.take(a, co["data_idx"], axis=0),
-                              data)
-        batch = sample(active, k_batch)
-        grads, norms = jax.vmap(lambda b: device_grad(params, b))(batch)
-        if fading is None:
-            h = ota.draw_fading(k_fade, co["gains"])
-        else:
-            fading_state, h = fading.step_cohort(fading_state, k_fade,
-                                                 co["gains"])
+        with jax.named_scope("fl.grad"):
+            active = jax.tree.map(
+                lambda a: jnp.take(a, co["data_idx"], axis=0), data)
+        grads, norms = grads_of(params, active, k_batch)
+        with jax.named_scope("fl.channel"):
+            if fading is None:
+                h = ota.draw_fading(k_fade, co["gains"])
+            else:
+                fading_state, h = fading.step_cohort(fading_state, k_fade,
+                                                     co["gains"])
         return finish(scheme, eta, params, fading_state, k_ota, h, grads,
                       norms)
 
     def scenario_body(scheme, eta, params, fading_state, key, data, sc):
         k_fade, k_ota, k_batch = jax.random.split(key, 3)
-        batch = sample(data, k_batch)
-        grads, norms = jax.vmap(lambda b: device_grad(params, b))(batch)
-        fading_state, h = sc.step(fading_state, k_fade)
+        grads, norms = grads_of(params, data, k_batch)
+        with jax.named_scope("fl.channel"):
+            fading_state, h = sc.step(fading_state, k_fade)
         return finish(scheme, eta, params, fading_state, k_ota, h, grads,
                       norms)
 
@@ -373,15 +390,14 @@ def run_rounds(loss_fn: Callable, params: PyTree, scheme: PowerControl,
         fading_state = fading.init(jax.random.fold_in(key, FADING_INIT_SALT))
 
     evals, metric_chunks, t = [], [], 0
-    wall_compile, first = 0.0, True
+    wall_compile = 0.0
     for length in chunk_lengths(run.num_rounds, run.eval_every,
                                 eval_fn is not None):
+        size0, t_call = chunk._cache_size(), time.time()
         params, fading_state, key, metrics = chunk(
             params, fading_state, key, data, length=length)
-        if first:
-            jax.block_until_ready(params)
-            wall_compile = time.time() - t0
-            first = False
+        if chunk._cache_size() > size0:
+            wall_compile += time.time() - t_call
         metric_chunks.append(metrics)
         t += length
         if eval_fn is not None:

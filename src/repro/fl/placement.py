@@ -23,8 +23,8 @@ A placement exposes:
         layout the stacked schemes' design leaves for this placement
         (vmap broadcasts non-adaptive designs over seeds; sharding tiles
         every leaf to the full [K, S] grid so it can flatten to cells).
-    build_chunk(round_body, adaptive, cohort=False, scenario=False,
-                tracer=None) -> chunk
+    build_chunk(round_body, adaptive, cohort=False, scenario=False)
+        -> chunk
         chunk(stacked, etas, params_b, fstate_b, keys_b, data, length)
         -> (params_b, fstate_b, keys_b, metrics), everything with leading
         [K, S] grid axes either way — the driver never knows where the
@@ -40,10 +40,11 @@ A placement exposes:
         Every chunk exposes ``_cache_size()`` — the number of compiled
         programs behind it (the jit trace cache here, the explicit
         per-(length, grid) dict on the sharded path) — which
-        ``telemetry.assert_no_recompile`` audits.  ``tracer`` (a
-        ``telemetry.Tracer``) emits a ``chunk_compile`` span whenever a
-        call grows that cache; ``None`` (default) returns the exact
-        pre-telemetry callable, bitwise.
+        ``telemetry.assert_no_recompile`` audits and the driver reads to
+        tell a call that compiled from one that only ran.  Chunks that
+        pad the cell grid to the device count also expose
+        ``_pad_frac()``, the fraction of compiled cells that are cell-0
+        copies.
 
         The carry buffers (``params_b``/``fstate_b``/``keys_b``) are
         DONATED to the compiled chunk (``jax.jit(...,
@@ -61,7 +62,6 @@ A placement exposes:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any
 
 import jax
@@ -75,39 +75,6 @@ from repro.launch.mesh import grid_axes
 PyTree = Any
 
 
-def _traced_compiles(chunk, tracer):
-    """Wrap a chunk so calls that grow its compile cache emit a
-    ``chunk_compile`` span (the jit call traces + compiles synchronously;
-    execution stays async, so the call duration on a cache-miss call IS
-    the compile wall to within dispatch noise).  The wrapper changes no
-    operand, shape or key stream — only observation.
-
-    Chunks that pad the cell grid to the device count (the sharded
-    placement) expose ``_pad_frac()``; the span then carries
-    ``padded_frac`` — the fraction of compiled cells that are cell-0
-    masking waste — so a 1000-cell grid on 8·P devices reports what the
-    padding burns instead of hiding it in the exec wall."""
-    def traced(*args, length):
-        before = chunk._cache_size()
-        t0 = time.monotonic()
-        out = chunk(*args, length=length)
-        after = chunk._cache_size()
-        if after > before:
-            extra = {}
-            pad = getattr(chunk, "_pad_frac", None)
-            frac = pad() if pad is not None else None
-            if frac is not None:
-                extra["padded_frac"] = round(frac, 6)
-            tracer.event("chunk_compile", dur=round(time.monotonic() - t0, 6),
-                         length=int(length), cache_size=after, **extra)
-        return out
-
-    traced._cache_size = chunk._cache_size
-    if hasattr(chunk, "_pad_frac"):
-        traced._pad_frac = chunk._pad_frac
-    return traced
-
-
 class Placement:
     """Interface marker; see module docstring for the contract."""
 
@@ -115,7 +82,7 @@ class Placement:
         raise NotImplementedError
 
     def build_chunk(self, round_body, adaptive: bool, cohort: bool = False,
-                    scenario: bool = False, tracer=None):
+                    scenario: bool = False):
         raise NotImplementedError
 
     def compile_batch(self, fn):
@@ -159,7 +126,7 @@ class VmapPlacement(Placement):
         return tile_over_seeds(stacked, s_axis) if adaptive else stacked
 
     def build_chunk(self, round_body, adaptive: bool, cohort: bool = False,
-                    scenario: bool = False, tracer=None):
+                    scenario: bool = False):
         if cohort and scenario:
             raise ValueError("cohort and scenario chunks are exclusive")
         if scenario:
@@ -180,8 +147,7 @@ class VmapPlacement(Placement):
 
             chunk = jax.jit(scenario_chunk, static_argnames=("length",),
                             donate_argnums=self._donate())
-            return chunk if tracer is None \
-                else _traced_compiles(chunk, tracer)
+            return chunk
 
         if not cohort:
             def fleet_chunk(stacked, etas, params_b, fstate_b, keys_b, data,
@@ -196,8 +162,7 @@ class VmapPlacement(Placement):
 
             chunk = jax.jit(fleet_chunk, static_argnames=("length",),
                             donate_argnums=self._donate())
-            return chunk if tracer is None \
-                else _traced_compiles(chunk, tracer)
+            return chunk
 
         # cohort leaves are [S, N]: per-seed active sets (each seed row
         # draws its own cohort), broadcast across the scheme axis
@@ -214,7 +179,7 @@ class VmapPlacement(Placement):
 
         chunk = jax.jit(cohort_chunk, static_argnames=("length",),
                         donate_argnums=self._donate())
-        return chunk if tracer is None else _traced_compiles(chunk, tracer)
+        return chunk
 
     def compile_batch(self, fn):
         return jax.jit(jax.vmap(fn))
@@ -266,7 +231,7 @@ class ShardedPlacement(Placement):
         return tile_over_seeds(stacked, s_axis)
 
     def build_chunk(self, round_body, adaptive: bool, cohort: bool = False,
-                    scenario: bool = False, tracer=None):
+                    scenario: bool = False):
         if cohort and scenario:
             raise ValueError("cohort and scenario chunks are exclusive")
         compiled = {}
@@ -290,8 +255,7 @@ class ShardedPlacement(Placement):
 
             scenario_chunk._cache_size = lambda: len(compiled)
             scenario_chunk._pad_frac = lambda: pad_info["frac"]
-            return scenario_chunk if tracer is None \
-                else _traced_compiles(scenario_chunk, tracer)
+            return scenario_chunk
 
         if not cohort:
             def chunk(stacked, etas, params_b, fstate_b, keys_b, data,
@@ -301,8 +265,7 @@ class ShardedPlacement(Placement):
 
             chunk._cache_size = lambda: len(compiled)
             chunk._pad_frac = lambda: pad_info["frac"]
-            return chunk if tracer is None \
-                else _traced_compiles(chunk, tracer)
+            return chunk
 
         def cohort_chunk(stacked, etas, params_b, fstate_b, keys_b, data,
                          cohort_b, length):
@@ -312,8 +275,7 @@ class ShardedPlacement(Placement):
 
         cohort_chunk._cache_size = lambda: len(compiled)
         cohort_chunk._pad_frac = lambda: pad_info["frac"]
-        return cohort_chunk if tracer is None \
-            else _traced_compiles(cohort_chunk, tracer)
+        return cohort_chunk
 
     def _compile(self, round_body, length: int, k: int, s: int):
         def cell(scheme, eta, params, fstate, key, data):

@@ -4,6 +4,11 @@ On CPU the kernel executes with interpret=True — the kernel body runs as
 traced JAX ops, validating indexing/masking/accumulation logic; on TPU the
 same pallas_call lowers to Mosaic.  Wrappers pad and tile the operands to
 the kernel's lane-dense layout.
+
+Everything that moves the gradient stack into and out of that layout —
+ravel, per-leaf noise draw, pad, tiling, quantization, unravel — runs
+under the ``fl.uplink`` name scope (``UPLINK_SCOPE``), so a profile tells
+it from the kernel itself, which the round body calls under ``fl.step``.
 """
 from __future__ import annotations
 
@@ -24,6 +29,9 @@ UPLINK_DTYPES = tuple(UPLINK_WIRE_BYTES)
 # int8 symmetric quantization: values map to [-127, 127] (the -128 code is
 # unused so the grid is symmetric around zero — standard for weights/grads)
 INT8_LEVELS = 127.0
+
+# name scope of the flat path's layout work (metadata only)
+UPLINK_SCOPE = "fl.uplink"
 
 
 def _on_cpu() -> bool:
@@ -123,16 +131,19 @@ def ota_round_step(g: jax.Array, s: jax.Array, z: jax.Array,
     wire_bytes = min(jnp.dtype(g.dtype).itemsize,
                      UPLINK_WIRE_BYTES.get(uplink_dtype, 4))
     rows, block_rows = rs.tile_rows(n, d, wire_bytes)
-    wire, q_scale = quantize_uplink(_tiled(g, rows), uplink_dtype)
-    qs = jnp.ones((n,), jnp.float32) if q_scale is None \
-        else q_scale.astype(jnp.float32)
-    coef = jnp.concatenate([jnp.asarray(noise_scale, jnp.float32).reshape(1),
-                            jnp.asarray(eta, jnp.float32).reshape(1),
-                            s.astype(jnp.float32), qs])[None]
-    out = rs.ota_round_step_pallas(wire, coef, _tiled(z, rows),
-                                   _tiled(params, rows),
+    with jax.named_scope(UPLINK_SCOPE):
+        wire, q_scale = quantize_uplink(_tiled(g, rows), uplink_dtype)
+        qs = jnp.ones((n,), jnp.float32) if q_scale is None \
+            else q_scale.astype(jnp.float32)
+        coef = jnp.concatenate(
+            [jnp.asarray(noise_scale, jnp.float32).reshape(1),
+             jnp.asarray(eta, jnp.float32).reshape(1),
+             s.astype(jnp.float32), qs])[None]
+        z_t, p_t = _tiled(z, rows), _tiled(params, rows)
+    out = rs.ota_round_step_pallas(wire, coef, z_t, p_t,
                                    block_rows=block_rows, interpret=interpret)
-    return out.reshape(-1)[:d]
+    with jax.named_scope(UPLINK_SCOPE):
+        return out.reshape(-1)[:d]
 
 
 def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
@@ -173,13 +184,14 @@ def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
     sizes = [int(np.prod(l.shape[1:])) for l in g_leaves]
     dtype = jnp.result_type(*[l.dtype for l in g_leaves])
     n = g_leaves[0].shape[0]
-    g = jnp.concatenate([l.reshape(n, -1).astype(dtype) for l in g_leaves],
-                        axis=1)
-    keys = jax.random.split(key, len(g_leaves))
-    z = jnp.concatenate([jax.random.normal(k, (sz,))
-                         for k, sz in zip(keys, sizes)]).astype(dtype)
-    p_flat = jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
-                              for l in p_leaves])
+    with jax.named_scope(UPLINK_SCOPE):
+        g = jnp.concatenate([l.reshape(n, -1).astype(dtype)
+                             for l in g_leaves], axis=1)
+        keys = jax.random.split(key, len(g_leaves))
+        z = jnp.concatenate([jax.random.normal(k, (sz,))
+                             for k, sz in zip(keys, sizes)]).astype(dtype)
+        p_flat = jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
+                                  for l in p_leaves])
     ns = jnp.asarray(noise_scale, dtype)
     eta32 = jnp.asarray(eta, jnp.float32)
     if use_kernel is None:
@@ -188,12 +200,14 @@ def ota_round_step_pytree(stacked, s: jax.Array, noise_scale,
         out = ota_round_step(g, s, z, ns, p_flat, eta32,
                              uplink_dtype=uplink_dtype, interpret=interpret)
     else:
-        wire, q_scale = quantize_uplink(g, uplink_dtype)
+        with jax.named_scope(UPLINK_SCOPE):
+            wire, q_scale = quantize_uplink(g, uplink_dtype)
         out = ref.ota_round_step_ref(wire, s, z, ns, p_flat, eta32,
                                      q_scale=q_scale)
     offsets = np.cumsum([0] + sizes)
-    parts = [out[offsets[i]:offsets[i + 1]].reshape(np.shape(l)).astype(
-        l.dtype) for i, l in enumerate(p_leaves)]
+    with jax.named_scope(UPLINK_SCOPE):
+        parts = [out[offsets[i]:offsets[i + 1]].reshape(np.shape(l)).astype(
+            l.dtype) for i, l in enumerate(p_leaves)]
     return jax.tree.unflatten(p_def, parts)
 
 
@@ -238,14 +252,15 @@ def ota_aggregate_pytree(stacked: jax.Array, s: jax.Array, noise_scale,
     sizes = [int(np.prod(l.shape[1:])) for l in leaves]
     dtype = jnp.result_type(*[l.dtype for l in leaves])
     n = leaves[0].shape[0]
-    g = jnp.concatenate([l.reshape(n, -1).astype(dtype) for l in leaves],
-                        axis=1)
-    if uplink_dtype != "f32":
-        wire, q_scale = quantize_uplink(g, uplink_dtype)
-        g = dequantize_uplink(wire, q_scale).astype(dtype)
-    keys = jax.random.split(key, len(leaves))
-    z = jnp.concatenate([jax.random.normal(k, (sz,))
-                         for k, sz in zip(keys, sizes)]).astype(dtype)
+    with jax.named_scope(UPLINK_SCOPE):
+        g = jnp.concatenate([l.reshape(n, -1).astype(dtype) for l in leaves],
+                            axis=1)
+        if uplink_dtype != "f32":
+            wire, q_scale = quantize_uplink(g, uplink_dtype)
+            g = dequantize_uplink(wire, q_scale).astype(dtype)
+        keys = jax.random.split(key, len(leaves))
+        z = jnp.concatenate([jax.random.normal(k, (sz,))
+                             for k, sz in zip(keys, sizes)]).astype(dtype)
     if use_kernel is None:
         use_kernel = not _on_cpu()
     if use_kernel:
@@ -254,6 +269,7 @@ def ota_aggregate_pytree(stacked: jax.Array, s: jax.Array, noise_scale,
         out = ref.ota_aggregate_ref(g, s, z,
                                     jnp.asarray(noise_scale, dtype))
     offsets = np.cumsum([0] + sizes)
-    parts = [out[offsets[i]:offsets[i + 1]].reshape(l.shape[1:]).astype(
-        l.dtype) for i, l in enumerate(leaves)]
+    with jax.named_scope(UPLINK_SCOPE):
+        parts = [out[offsets[i]:offsets[i + 1]].reshape(l.shape[1:]).astype(
+            l.dtype) for i, l in enumerate(leaves)]
     return jax.tree.unflatten(treedef, parts)

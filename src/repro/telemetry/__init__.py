@@ -3,7 +3,9 @@
 Three layers, all opt-in:
 
 ``trace``        structured JSONL span/event writer (run id, monotonic
-                 clocks, line-atomic appends, kill-and-resume pruning).
+                 and wall clocks, line-atomic appends, kill-and-resume
+                 pruning) and the ``compile.*`` spans of JAX's compile
+                 phases (``set_compile_tracer``).
 ``diagnostics``  in-graph Theorem-1 collectors — realized OTA bias power
                  and effective noise variance per [K, S] cell, riding the
                  engine's ``hist.traces`` mechanism.
@@ -25,12 +27,13 @@ from typing import Optional
 
 from repro.telemetry.diagnostics import (DIAG_PREFIX, is_diagnostic,
                                          make_metrics_hook)
-from repro.telemetry.trace import EVENTS_FILE, Tracer, read_events
+from repro.telemetry.trace import (EVENTS_FILE, Tracer, read_events,
+                                   set_compile_tracer)
 
 __all__ = [
     "DIAG_PREFIX", "EVENTS_FILE", "Telemetry", "Tracer",
     "assert_no_recompile", "chunk_cache_size", "is_diagnostic",
-    "make_metrics_hook", "read_events",
+    "make_metrics_hook", "read_events", "set_compile_tracer",
 ]
 
 
@@ -42,7 +45,8 @@ class Telemetry:
                  same directory (put the fleet checkpoint next to it to
                  get the bias-variance trajectory in the report too).
     trace        emit the structured event stream (spans for chunk exec,
-                 cohort staging, redesign, checkpoint I/O, SCA solves).
+                 cohort staging, redesign, checkpoint I/O, SCA solves,
+                 and JAX's compile phases).
     diagnostics  add the in-graph ``bv_*`` Theorem-1 traces to every
                  round's metrics (recorded into FLResult.traces and any
                  fleet checkpoint; keep the setting consistent across a
@@ -53,8 +57,9 @@ class Telemetry:
     Overhead contract: diagnostics are a handful of extra scalar
     reductions fused into the already-compiled chunk (no host syncs, no
     extra dispatches); tracing adds one ``block_until_ready`` per chunk
-    for honest exec attribution plus O(events) tiny host writes — walls
-    may shift, math never does (stream/serial and resume stay bitwise).
+    for honest exec attribution plus O(events) tiny host writes and one
+    JAX monitoring listener for the run — walls may shift, math never
+    does (stream/serial and resume stay bitwise).
     """
     run_dir: str
     trace: bool = True

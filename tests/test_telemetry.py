@@ -15,10 +15,17 @@ Contracts pinned here:
     run id, exactly one run_resume, no duplicated chunk_exec spans, and
     numerics bitwise vs the uninterrupted telemetry-on run.
   * the report tool renders a real run directory without error.
+  * the round step's layers and the eval are name scopes in the compiled
+    programs' op metadata; JAX's compile phases become ``compile.*``
+    spans inside the chunk call or eval that paid them, every event
+    carries wall-clock ``t0_ns``/``t1_ns``, a chunk's exec span starts
+    where its compile ends, and ``FLResult.wall_compile`` is the summed
+    wall of the calls that compiled.
 """
 import io
 import json
 import os
+import re
 from contextlib import redirect_stdout
 
 import jax
@@ -26,7 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax._src import monitoring as jax_monitoring
+
 from repro import telemetry
+from repro.telemetry import trace as tlm_trace
 from repro.core import channel, power_control as pcm, scenarios as scn
 from repro.data import partition, synthetic
 from repro.fl import driver, engine as eng
@@ -157,15 +167,48 @@ def test_telemetry_on_is_bitwise_off_plus_diagnostics(pop_world, tmp_path):
     assert all(w >= 0 for w in res_on.stage_walls)
 
 
-def test_telemetry_off_adds_no_traces_and_no_files(pop_world, tmp_path):
+def _listeners():
+    return (jax_monitoring.get_event_time_span_listeners(),
+            jax_monitoring.get_scalar_listeners())
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` and counts entries."""
+    entered = 0
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        type(self).entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_telemetry_off_adds_no_traces_and_no_files(pop_world, tmp_path,
+                                                   monkeypatch):
     dep, prm, data, params0, ev, pop = pop_world
     schemes = [pcm.make_power_control("ideal", dep, prm)]
     run = FLRunConfig(eta=0.05, num_rounds=2, eval_every=2)
+    listeners = _listeners()
+    monkeypatch.setattr(_CountingAnnotation, "entered", 0)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    seen = []
+    monkeypatch.setattr(telemetry, "set_compile_tracer", seen.append)
     res = driver.run_fleet(mlp.mlp_loss, params0, schemes, dep.gains, data,
                            run, ev, flat=False, population=pop,
                            cohort_size=10)
     assert not any(telemetry.is_diagnostic(k) for k in res.traces)
     assert list(tmp_path.iterdir()) == []
+    # no monitoring listener registered or left, no annotation entered
+    assert seen == [] and tlm_trace._COMPILE_TRACER is None
+    after = _listeners()
+    assert after == listeners
+    assert tlm_trace._on_compile_phase not in after[0]
+    assert tlm_trace._on_compile_start not in after[1]
+    assert _CountingAnnotation.entered == 0
 
 
 def test_telemetry_kill_and_resume_single_log(pop_world, tmp_path):
@@ -280,3 +323,144 @@ def test_run_dir_string_shorthand(pop_world, tmp_path):
                            cohort_size=10, telemetry=run_dir)
     assert any(telemetry.is_diagnostic(k) for k in res.traces)
     assert os.path.exists(os.path.join(run_dir, telemetry.EVENTS_FILE))
+
+
+# ---------------------------------------------------------------------------
+# name scopes and compile phases
+# ---------------------------------------------------------------------------
+
+SCOPES = ("fl.grad", "fl.channel", "fl.step", "fl.uplink")
+
+
+def _op_scopes(hlo_text: str) -> set:
+    """The ``fl.`` scopes named in the op_name metadata of an HLO text."""
+    names = re.findall(r'op_name="([^"]*)"', hlo_text)
+    return {m for n in names for m in re.findall(r"fl\.[a-z]+", n)}
+
+
+@pytest.mark.parametrize("flat", [False, True])
+def test_compiled_chunk_names_the_round_step_scopes(pop_world, flat):
+    from repro.fl.placement import VmapPlacement
+
+    dep, prm, data, params0, ev, _ = pop_world
+    schemes = pcm.stack_schemes(
+        [pcm.make_power_control(n, dep, prm) for n in ("sca", "vanilla")])
+    run = FLRunConfig(eta=0.05, num_rounds=2, eval_every=2, batch_size=8)
+    body = eng.make_round_body(mlp.mlp_loss, dep.gains, run, flat=flat)
+    chunk = VmapPlacement(donate=False).build_chunk(body, adaptive=False)
+    params_b = jax.tree.map(
+        lambda a: jnp.tile(a[None, None], (2, 1) + (1,) * a.ndim), params0)
+    keys_b = jnp.tile(jax.random.PRNGKey(0)[None, None], (2, 1, 1))
+    text = chunk.lower(schemes, jnp.asarray([0.05, 0.05]), params_b, None,
+                       keys_b, tuple(jnp.asarray(a) for a in data),
+                       length=2).compile().as_text()
+    want = set(SCOPES) if flat else set(SCOPES) - {"fl.uplink"}
+    assert _op_scopes(text) == want
+    # the eval's program carries its own scope
+    text = driver.grid_eval(ev).lower(params_b).compile().as_text()
+    assert _op_scopes(text) == {"fl.eval"}
+
+
+def test_round_step_kernel_separates_uplink_from_step():
+    from repro.kernels import ops
+
+    grads = {"a": jnp.ones((10, 300)), "b": jnp.ones((10, 7, 5))}
+    params = {"a": jnp.ones((300,)), "b": jnp.ones((7, 5))}
+
+    def tail(g, p):
+        with jax.named_scope("fl.step"):
+            return ops.ota_round_step_pytree(
+                g, jnp.ones(10), 0.1, jax.random.PRNGKey(0), p, 0.05,
+                use_kernel=True, interpret=True)
+
+    text = jax.jit(tail).lower(grads, params).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    inner = {re.findall(r"fl\.[a-z]+", n)[-1] for n in names
+             if "fl." in n}
+    assert inner == {"fl.step", "fl.uplink"}
+    # the layout work is fl.uplink, the kernel itself stays fl.step
+    assert any(n.endswith("/pad") and "fl.uplink" in n for n in names)
+
+
+@pytest.fixture(scope="module")
+def traced_fleet(pop_world, tmp_path_factory):
+    """A plain telemetry-on fleet: three chunk lengths, an eval each."""
+    dep, prm, data, params0, ev, _ = pop_world
+    schemes = [pcm.make_power_control(n, dep, prm)
+               for n in ("sca", "vanilla")]
+    # chunk lengths [1, 3, 2]: three programs
+    run = FLRunConfig(eta=0.05, num_rounds=6, eval_every=3, batch_size=8)
+    run_dir = str(tmp_path_factory.mktemp("traced") / "run")
+    listeners = _listeners()
+    res = driver.run_fleet(
+        mlp.mlp_loss, params0, schemes, dep.gains, data, run, ev,
+        seeds=(0, 1), flat=True,
+        telemetry=telemetry.Telemetry(run_dir=run_dir, diagnostics=False))
+    return res, telemetry.read_events(run_dir), listeners
+
+
+def test_compile_phases_are_spans_inside_the_calls_that_paid_them(
+        traced_fleet):
+    res, events, listeners = traced_fleet
+    phases = [e for e in events if e["ev"].startswith("compile.")]
+    kinds = {e["ev"] for e in phases}
+    assert kinds == {"compile.jaxpr_trace", "compile.lower",
+                     "compile.backend"}
+    for e in events:
+        assert e["t0_ns"] <= e["t1_ns"] and "mono" in e, e
+    holders = [e for e in events if e["ev"] in ("chunk_compile", "eval")]
+    for e in phases:
+        assert e["dur"] > 0 or e["ev"] == "compile.jaxpr_trace", e
+        assert isinstance(e.get("fun"), str) and e.get("chunk") is not None
+        assert any(h["t0_ns"] <= e["t0_ns"] and e["t1_ns"] <= h["t1_ns"]
+                   for h in holders), e
+    for kind in kinds:
+        assert max(e["dur"] for e in phases if e["ev"] == kind) > 0, kind
+    # the chunk's own trace, lowering and backend step, and the eval's
+    funs = {(e["ev"], e["fun"]) for e in phases}
+    assert ("compile.backend", "jit(fleet_chunk)") in funs
+    evals = [e for e in events if e["ev"] == "eval"]
+    assert any(e["ev"] == "compile.backend" and e["fun"] != "jit(fleet_chunk)"
+               and evals[0]["t0_ns"] <= e["t0_ns"] <= evals[0]["t1_ns"]
+               for e in phases)
+    # the end ``mono`` and ``dur`` place a phase on the monotonic clock
+    # where its wall-clock stamps place it
+    for e in phases:
+        assert e["dur"] == pytest.approx((e["t1_ns"] - e["t0_ns"]) / 1e9,
+                                         abs=2e-6)
+    # only a thread's outermost phases are written: none holds another
+    for e in phases:
+        assert not any(o is not e and o["t0_ns"] <= e["t0_ns"]
+                       and e["t1_ns"] <= o["t1_ns"] for o in phases), e
+    # the listeners are gone once the run ends
+    assert _listeners() == listeners
+    assert tlm_trace._COMPILE_TRACER is None
+
+
+def test_chunk_exec_starts_where_its_compile_ends(traced_fleet):
+    res, events, _ = traced_fleet
+    compiles = {e["chunk"]: e for e in events if e["ev"] == "chunk_compile"}
+    execs = {e["chunk"]: e for e in events if e["ev"] == "chunk_exec"}
+    assert len(compiles) == 3 and set(compiles) <= set(execs)
+    for ci, c in compiles.items():
+        assert execs[ci]["t0_ns"] >= c["t1_ns"], ci
+    # execution of a chunk that compiled is not the compile's length
+    assert all(execs[ci]["dur"] < c["dur"] for ci, c in compiles.items())
+
+
+def test_wall_compile_is_the_summed_compile_calls(traced_fleet, pop_world):
+    res, events, _ = traced_fleet
+    spans = [e["dur"] for e in events if e["ev"] == "chunk_compile"]
+    assert res.wall_compile == pytest.approx(sum(spans), abs=1e-5 * 3)
+    assert res.wall_exec == pytest.approx(res.wall - res.wall_compile)
+    assert 0 < res.wall_compile < res.wall
+    # the same split with telemetry off: every chunk length compiles once
+    dep, prm, data, params0, ev, _ = pop_world
+    run = FLRunConfig(eta=0.05, num_rounds=3, eval_every=2, batch_size=8)
+    pc = pcm.make_power_control("vanilla", dep, prm)
+    off = driver.run_fleet(mlp.mlp_loss, params0, [pc], dep.gains, data,
+                           run, ev, flat=False)
+    assert 0 < off.wall_compile < off.wall
+    single = eng.run_rounds(mlp.mlp_loss, params0, pc, dep.gains, data, run,
+                            ev)
+    assert 0 < single.wall_compile < single.wall
