@@ -31,10 +31,13 @@ needs no RNG cursor: a restart re-derives every draw from the chunk index.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import hashlib
 import os
+import threading
 import time
+from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
@@ -223,6 +226,96 @@ def grid_eval(eval_fn: Callable):
     return jax.jit(scoped)
 
 
+# -- compiled fleet programs, reused across calls (DESIGN.md §Placement) ----
+# JAX keys its trace cache on the function object, so a call that built
+# new jitted chunks would trace, lower and fetch every chunk length again.
+# These two small caches hand a call the jitted chunk and eval an earlier
+# call built, under a key that holds exactly what the traced programs close
+# over or branch on.  The bounds are constants: the oldest entry goes, and
+# with it whatever its closures hold (an eval's test arrays).
+_CHUNK_CACHE_SIZE = 8
+_EVAL_CACHE_SIZE = 4
+_chunk_cache: OrderedDict = OrderedDict()
+_eval_cache: OrderedDict = OrderedDict()
+_chunk_counts = {"hit": 0, "miss": 0}
+_cache_lock = threading.Lock()
+
+
+def _content_key(obj):
+    """A hashable key that changes whenever ``obj``'s content does: scalars
+    by type and value, arrays by digest, tuples and dataclass instances
+    (every instance attribute) item by item.  TypeError for anything else,
+    which has no safe content key."""
+    if obj is None or isinstance(obj, (bool, int, float, str)):
+        return (type(obj), obj)
+    if isinstance(obj, (np.ndarray, np.generic, jax.Array)):
+        return ("array", _array_digest(obj))
+    if isinstance(obj, tuple):
+        return tuple(_content_key(v) for v in obj)
+    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
+        return (type(obj),) + tuple((k, _content_key(v))
+                                    for k, v in sorted(vars(obj).items()))
+    raise TypeError(f"no content key for {type(obj).__name__}")
+
+
+def _chunk_key(loss_fn, gains, run, *, uplink_dtype, flat, fuse_round,
+               cohort, scenario, adaptive, kappa_sq, fading, placement):
+    """The chunk cache's key: what ``make_round_body`` and
+    ``placement.build_chunk`` close over or branch on.  Everything else a
+    call varies (rounds, eval cadence, eta, seeds, design leaves, data,
+    params) is an operand or drives only the host loop.  None where some
+    part has no safe key: that call builds its own chunk."""
+    try:
+        key = (loss_fn,
+               None if gains is None else _array_digest(gains),
+               _content_key(run.batch_size), _content_key(run.clip_to_gmax),
+               _content_key(run.gmax), str(uplink_dtype), bool(flat),
+               bool(flat) if fuse_round is None else bool(fuse_round),
+               bool(cohort), bool(scenario), bool(adaptive),
+               _content_key(kappa_sq), _content_key(fading), placement)
+    except TypeError:
+        return None
+    return key
+
+
+def _cached(cache: OrderedDict, key, size: int, build: Callable):
+    """(value, hit): ``cache[key]``, else ``build()`` stored under ``key``
+    with the least recently used entry dropped past ``size``.  A None or
+    unhashable key builds and stores nothing."""
+    try:
+        hash(key)
+    except TypeError:
+        key = None
+    if key is None:
+        return build(), False
+    with _cache_lock:
+        if key in cache:
+            cache.move_to_end(key)
+            return cache[key], True
+    value = build()
+    with _cache_lock:
+        cache[key] = value
+        while len(cache) > size:
+            cache.popitem(last=False)
+    return value, False
+
+
+def chunk_cache_stats() -> dict:
+    """Process-wide chunk-cache lookups (``hit``/``miss``, one per
+    ``run_fleet`` call) and the entries held (``chunks``/``evals``)."""
+    with _cache_lock:
+        return {**_chunk_counts, "chunks": len(_chunk_cache),
+                "evals": len(_eval_cache)}
+
+
+def clear_chunk_cache() -> None:
+    """Drop every cached chunk and eval program: the next call compiles as
+    in a fresh process.  The hit/miss counts stay."""
+    with _cache_lock:
+        _chunk_cache.clear()
+        _eval_cache.clear()
+
+
 def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
               data: tuple, run, eval_fn: Optional[Callable] = None, *,
               etas=None, seeds: Optional[Sequence[int]] = None, fading=None,
@@ -309,6 +402,23 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                      "scenario/scheme"; the scenario axis joins the
                      checkpoint identity.  Exclusive with population mode
                      and adaptive (redesign_fn) schemes.
+
+    Compiled programs outlive the call.  The jitted chunk and eval a call
+    builds are kept in small process-wide caches (DESIGN.md §Placement) and
+    handed to later calls whose traced programs would be the same: the
+    same ``loss_fn`` and ``eval_fn`` objects, gains content, the run's
+    ``batch_size``/``clip_to_gmax``/``gmax``, uplink dtype, ``flat``,
+    ``fuse_round``, mode, diagnostics, fading content and placement; the
+    ``fleet_config`` event says ``chunk_cache: "hit"``.  A hit traces
+    nothing for the chunk lengths an earlier call ran at the same shapes
+    (cells, seeds, devices, data, params): a repeat of the same sweep
+    reports ``wall_compile`` 0 and writes no ``chunk_compile`` span.  A
+    hit at other shapes or chunk lengths traces them on the reused chunk
+    and counts them as compile, as a fresh call would.  A call with a
+    part that has no safe key (an unhashable placement, a fading object
+    that is not a dataclass) builds its own chunk, as every call did
+    before.  ``chunk_cache_stats`` and ``clear_chunk_cache`` read and
+    empty the caches.
 
     Adaptive schemes (``power_control.AdaptiveSCA``) re-design BETWEEN
     chunks from the live fading state, whatever the placement: the state
@@ -408,14 +518,26 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
         return tracer.ctx(**fields) if tracer is not None \
             else contextlib.nullcontext()
 
-    round_body = make_round_body(loss_fn, gains, run, fading=fading,
-                                 flat=flat, cohort=pop_mode,
-                                 scenario=scen_mode,
-                                 metrics_hook=metrics_hook,
-                                 uplink_dtype=uplink_dtype,
-                                 fuse_round=fuse_round)
-    chunk = placement.build_chunk(round_body, adaptive or pop_adaptive,
-                                  cohort=pop_mode, scenario=scen_mode)
+    def _build_chunk():
+        round_body = make_round_body(loss_fn, gains, run, fading=fading,
+                                     flat=flat, cohort=pop_mode,
+                                     scenario=scen_mode,
+                                     metrics_hook=metrics_hook,
+                                     uplink_dtype=uplink_dtype,
+                                     fuse_round=fuse_round)
+        return placement.build_chunk(round_body, adaptive or pop_adaptive,
+                                     cohort=pop_mode, scenario=scen_mode)
+
+    chunk_key = _chunk_key(
+        loss_fn, gains, run, uplink_dtype=uplink_dtype, flat=flat,
+        fuse_round=fuse_round, cohort=pop_mode, scenario=scen_mode,
+        adaptive=adaptive or pop_adaptive,
+        kappa_sq=None if metrics_hook is None else tel.kappa_sq,
+        fading=fading, placement=placement)
+    chunk, hit = _cached(_chunk_cache, chunk_key, _CHUNK_CACHE_SIZE,
+                         _build_chunk)
+    with _cache_lock:
+        _chunk_counts["hit" if hit else "miss"] += 1
 
     data = tuple(jnp.asarray(a) for a in data)
     params_b = jax.tree.map(
@@ -443,7 +565,10 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
         # cohort states are staged per chunk from the re-entry table
         pop_table = population.init_table(s_axis)
 
-    eval_b = None if eval_fn is None else grid_eval(eval_fn)
+    eval_b = None
+    if eval_fn is not None:
+        eval_b, _ = _cached(_eval_cache, eval_fn, _EVAL_CACHE_SIZE,
+                            lambda: grid_eval(eval_fn))
 
     designs = None
     if adaptive:
@@ -540,7 +665,8 @@ def run_fleet(loss_fn: Callable, params: PyTree, schemes, gains: np.ndarray,
                      cohort_size=n_cohort, cohort_rounds=cohort_cadence,
                      scenarios=(list(scenarios.names) if scen_mode
                                 else None),
-                     stream=bool(stream), start_chunk=start_chunk)
+                     stream=bool(stream), start_chunk=start_chunk,
+                     chunk_cache="hit" if hit else "miss")
     last_tick = _tick_of(start_chunk - 1) \
         if pop_mode and start_chunk > 0 else None
 

@@ -74,7 +74,11 @@ class FLResult:
                   compile cache: each traces, lowers and compiles (or
                   fetches from the persistent cache) before it dispatches,
                   so its wall is compile; benchmark speedups quote it
-                  separately so compile never inflates throughput
+                  separately so compile never inflates throughput.  The
+                  fleet driver reuses a chunk across calls
+                  (``fl.driver.run_fleet``), so a call whose chunk lengths
+                  an earlier call already ran at the same shapes compiled
+                  nothing and reads 0
     wall_exec     the rest: set-up, execution, eval and host work between
                   chunks
     fading_state  final FadingProcess state (None on the i.i.d. path)
@@ -304,8 +308,9 @@ def chunk_lengths(num_rounds: int, eval_every: int, with_eval: bool,
                   cohort_rounds: Optional[int] = None) -> list:
     """Scan chunk lengths whose boundaries hit the legacy eval cadence
     (t % eval_every == 0 or t == num_rounds - 1).  At most three distinct
-    lengths occur — {1, eval_every, tail} — so at most three scan programs
-    ever compile per engine.
+    lengths occur — {1, eval_every, tail} — so a run compiles at most three
+    scan programs, and a fleet call whose lengths an earlier call with the
+    same cached chunk ran compiles none (``fl.driver.run_fleet``).
 
     ``cohort_rounds`` adds population-cohort boundaries: the active set
     changes BEFORE every round t with t % cohort_rounds == 0, so chunks

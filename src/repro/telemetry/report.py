@@ -19,8 +19,11 @@ is the task's artifact dir, e.g. ``experiments/fig2``).  Sections:
                checkpoint in the run dir (``--npz`` overrides).
   staleness    cohort participation + re-entry staleness histograms from
                ``cohort`` events (per-device rounds-since-last-seen).
-  recompiles   every ``chunk_compile`` span; lengths that compiled more
-               than once are flagged — the recompilation audit.
+  recompiles   whether each run found its jitted chunk in the driver's
+               cross-call cache (``chunk_cache`` hit/miss: a hit compiles
+               only chunk lengths or shapes no earlier call ran) and every
+               ``chunk_compile`` span; lengths that compiled more than once
+               are flagged — the recompilation audit.
 
 Everything is plain text on stdout; the tool only reads the run dir.
 """
@@ -233,17 +236,26 @@ def staleness(events, flat) -> None:
 
 
 def recompiles(events) -> None:
-    # a resumed process starts with a cold jit cache, so compiles repeat
+    # a resumed process may start with a cold jit cache, so compiles repeat
     # across run_resume boundaries by design — only a length compiled
     # twice WITHIN one process is a real recompilation
-    seg, comp = 0, []
+    seg, comp, cache = 0, [], []
     for e in events:
         if e["ev"] == "run_resume":
             seg += 1
+        elif e["ev"] == "fleet_config" and e.get("chunk_cache"):
+            cache.append((seg, e["chunk_cache"]))
         elif e["ev"] == "chunk_compile":
             comp.append((seg, e))
+    for sg, state in cache:
+        print(f"  process {sg} chunk_cache: {state}"
+              + (" (jitted chunk reused from an earlier call)"
+                 if state == "hit" else ""))
     if not comp:
-        print("(no compiles recorded)")
+        print("(no compiles recorded"
+              + ("; the chunk cache held every program run"
+                 if any(state == "hit" for _, state in cache) else "")
+              + ")")
         return
     by_key = defaultdict(list)
     for sg, e in comp:

@@ -14,3 +14,11 @@ def rng():
 @pytest.fixture(scope="session")
 def key():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def cold_fleet_cache():
+    """Empty the fleet driver's cross-call program cache first, so that the
+    test's first ``run_fleet`` call compiles as in a fresh process."""
+    from repro.fl import driver
+    driver.clear_chunk_cache()

@@ -293,7 +293,8 @@ def test_describe_reports_pad_waste():
 
 @needs_mesh
 def test_sharded_chunk_compile_event_carries_padded_frac(grid_world,
-                                                         tmp_path):
+                                                         tmp_path,
+                                                         cold_fleet_cache):
     """[C=3, K=2, S=3] = 18 cells on a 2x2 mesh pads to 20: the compile
     telemetry must say 10% of the compiled cells are masking waste."""
     stack, flat_pcs = _grid_inputs()

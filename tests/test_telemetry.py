@@ -285,28 +285,39 @@ def test_resume_telemetry_does_not_change_numbers_vs_off(pop_world,
 # report tool
 # ---------------------------------------------------------------------------
 
-def test_report_renders_run_dir(pop_world, tmp_path):
+def test_report_renders_run_dir(pop_world, tmp_path, cold_fleet_cache):
     dep, prm, data, params0, ev, pop = pop_world
     pc = pcm.make_power_control("adaptive_sca", dep, prm)
     run = FLRunConfig(eta=0.05, num_rounds=6, eval_every=3)
-    run_dir = str(tmp_path / "run")
-    tel = telemetry.Telemetry(run_dir=run_dir,
-                              kappa_sq=float(prm.kappa_sq))
-    driver.run_fleet(mlp.mlp_loss, params0, [pc], dep.gains, data, run, ev,
-                     seeds=(0,), flat=False, population=pop, cohort_size=10,
-                     cohort_rounds=2,
-                     checkpoint_path=os.path.join(run_dir, "fleet"),
-                     telemetry=tel)
-    out = io.StringIO()
-    with redirect_stdout(out):
-        tlm_report.main([run_dir])
-    text = out.getvalue()
+
+    def fleet(run_dir):
+        tel = telemetry.Telemetry(run_dir=run_dir,
+                                  kappa_sq=float(prm.kappa_sq))
+        driver.run_fleet(mlp.mlp_loss, params0, [pc], dep.gains, data, run,
+                         ev, seeds=(0,), flat=False, population=pop,
+                         cohort_size=10, cohort_rounds=2,
+                         checkpoint_path=os.path.join(run_dir, "fleet"),
+                         telemetry=tel)
+        out = io.StringIO()
+        with redirect_stdout(out):
+            tlm_report.main([run_dir])
+        return out.getvalue()
+
+    text = fleet(str(tmp_path / "run"))
     for section in ("staging-lane timeline", "SCA solver",
                     "bias--variance trajectory", "cohort staleness",
                     "recompilation audit"):
         assert section in text, section
     assert "bv_bias_power" in text and "bv_noise_var" in text
     assert "staging overlap" in text
+    audit = text.split("recompilation audit")[1]
+    assert "chunk_cache: miss" in audit and "no recompilation" in audit
+    # the same fleet again: its chunk programs come from the driver's
+    # cache, and the audit reads the missing compiles as that reuse
+    audit = fleet(str(tmp_path / "hit")).split("recompilation audit")[1]
+    assert "chunk_cache: hit" in audit
+    assert "no compiles recorded; the chunk cache held every program" \
+        in audit
     with pytest.raises(SystemExit, match="events.jsonl"):
         tlm_report.main([str(tmp_path / "empty")])
 
@@ -392,6 +403,9 @@ def traced_fleet(pop_world, tmp_path_factory):
     run = FLRunConfig(eta=0.05, num_rounds=6, eval_every=3, batch_size=8)
     run_dir = str(tmp_path_factory.mktemp("traced") / "run")
     listeners = _listeners()
+    # compile as in a fresh process: an earlier test may have cached these
+    # programs (the module scope rules out the cold_fleet_cache fixture)
+    driver.clear_chunk_cache()
     res = driver.run_fleet(
         mlp.mlp_loss, params0, schemes, dep.gains, data, run, ev,
         seeds=(0, 1), flat=True,
@@ -448,7 +462,8 @@ def test_chunk_exec_starts_where_its_compile_ends(traced_fleet):
     assert all(execs[ci]["dur"] < c["dur"] for ci, c in compiles.items())
 
 
-def test_wall_compile_is_the_summed_compile_calls(traced_fleet, pop_world):
+def test_wall_compile_is_the_summed_compile_calls(traced_fleet, pop_world,
+                                                  cold_fleet_cache):
     res, events, _ = traced_fleet
     spans = [e["dur"] for e in events if e["ev"] == "chunk_compile"]
     assert res.wall_compile == pytest.approx(sum(spans), abs=1e-5 * 3)
