@@ -19,10 +19,16 @@ cell's ``limits/<cell>.json``:
                  that leaf or of the median leaf, whichever is larger;
                  leaves whose round-0 reference gradient is under a
                  thousandth of the median leaf's are left out
+
+A leaf is named by its key path (``reference.leaf_names``), so the
+parameters may be any tree.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
+
+from bench.reference import leaf_names
 
 GRAD_ROUNDS = 3
 LEAF_RULE = 1e-3
@@ -31,12 +37,10 @@ LEAF_RULE = 1e-3
 def gather(result, picks: list) -> dict:
     """The checked cells of a program ``FLResult`` as host arrays, in the
     layout ``reference.simulate`` returns."""
-    import jax
-
-    params = {}
-    for name, leaf in result.params.items():
-        params[name] = np.stack([np.asarray(jax.device_get(leaf[r, s]),
-                                            np.float32) for r, s in picks])
+    params = {name: np.stack([np.asarray(jax.device_get(leaf[r, s]),
+                                         np.float32) for r, s in picks])
+              for name, leaf in zip(leaf_names(result.params),
+                                    jax.tree.leaves(result.params))}
     evals = [(int(t), {k: np.asarray([np.asarray(ev[k])[r, s]
                                       for r, s in picks], np.float64)
                        for k in ("global_loss", "acc")})
@@ -72,12 +76,14 @@ def numbers(prog: dict, ref: dict, params0: dict) -> dict:
     out["noise_scale"] = _rel(pt["noise_scale"], rt["noise_scale"])
     out["active"] = float(np.max(np.abs(pt["active_devices"]
                                         - rt["active_devices"])))
-    names = sorted(ref["params"])
+    # leaf_grad's order: the tree's flatten order, as ref["params"] keeps
+    names = list(ref["params"])
+    p0 = {k: np.asarray(v, np.float64)
+          for k, v in zip(leaf_names(params0), jax.tree.leaves(params0))}
     worst = 0.0
     for c in range(ref["leaf_grad"].shape[0]):
         grad = ref["leaf_grad"][c]
         keep = grad >= LEAF_RULE * np.median(grad)
-        p0 = {k: np.asarray(params0[k], np.float64) for k in names}
         dp = np.array([np.linalg.norm(prog["params"][k][c] - p0[k])
                        for k in names])
         dr = np.array([np.linalg.norm(ref["params"][k][c] - p0[k])

@@ -4,6 +4,22 @@
 
 Written from the paper's description; it imports nothing of the program.
 Every function takes the configuration dict of ``paper_mlp.json``.
+
+The functions a configuration module gives the benchmark:
+
+    init_params(key, cfg)   initial weights, any tree of arrays
+    make_data(key, cfg)     {"train_x", "train_y"} with leading [N, M]
+                            axes (devices, samples per device), and
+                            "test_x", "test_y", "global_x", "global_y";
+                            floating inputs take the reference's dtype,
+                            integer ones (labels, token ids) keep theirs
+    loss(params, batch, cfg), logits(params, x)
+    flops_per_sample(cfg)   forward plus backward of one sample
+    samples_per_device(cfg, batch_size), optional: the samples a device
+                            trains on per round; where it is absent
+                            (as here), ``model.step_mfu`` takes the
+                            shard of samples_per_class x num_classes over
+                            the devices, cut to the minibatch
 """
 from __future__ import annotations
 

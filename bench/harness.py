@@ -7,9 +7,15 @@ power-control designs on the host, and one short warm-up sweep whose
 chunk programs are the window's.  The window calls the timed path back
 to back, one whole sweep per call; a sweep that has started is finished,
 and the window ends when the last sweep that started inside ``seconds``
-ends.  With ``trace`` the window is ``TRACE_SWEEPS`` sweeps under the JAX profiler and the program's telemetry, and the run
-reports the per-layer metrics instead of the end-to-end ones.  Then the
-first sweep of the window is checked against the plain reference.
+ends.  As soon as the first sweep returns, its checked cells (params,
+evals, traces) are copied to the host and the rest of its result is
+dropped, so the device holds no earlier sweep while later ones run; the
+copy's measured seconds are left out of the window, on the host clock
+and in the traced window alike.  With ``trace`` the window is
+``TRACE_SWEEPS`` sweeps under the JAX profiler and the program's
+telemetry, and the run reports the per-layer metrics instead of the
+end-to-end ones.  Then the checked cells are held against the plain
+reference.
 """
 from __future__ import annotations
 
@@ -131,11 +137,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         from repro.telemetry import Telemetry
         jax.profiler.start_trace(trace_dir)
     attempted = failed = 0
-    checked = None
+    picks = sweep.check_cells(seed, n_rows, n_seeds, CHECK_CELLS)
+    prog, copy_s = None, 0.0
     counter.on = True
     w0 = time.monotonic()
     while (trace and attempted < TRACE_SWEEPS) or \
-            (not trace and time.monotonic() - w0 < seconds):
+            (not trace and time.monotonic() - w0 - copy_s < seconds):
         i = attempted
         attempted += 1
         telemetry = None
@@ -157,15 +164,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             failed += 1
             res = None
             _log(f"# sweep {i} failed:\n{traceback.format_exc()}")
-        if i == 0:
-            checked = res
+        if i == 0 and res is not None:
+            c0 = time.monotonic()
+            with jax.profiler.TraceAnnotation("bench.copy"):
+                prog = check.gather(res, picks)
+            copy_s = time.monotonic() - c0
         res = None
     w1 = time.monotonic()
     counter.on = False
     if trace:
         jax.profiler.stop_trace()
     # a cache hit reports a backend compile too: what is left compiled anew
-    _log(f"# window {w1 - w0:.3f} s, {attempted} sweeps, {failed} failed, "
+    window = w1 - w0 - copy_s
+    _log(f"# window {window:.3f} s (the copy of the checked cells, "
+         f"{copy_s:.3f} s, left out), {attempted} sweeps, "
+         f"{failed} failed, "
          f"{counter.fetches} programs fetched from the compile cache, "
          f"{counter.compiles - counter.fetches} compiled")
 
@@ -179,9 +192,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         # and reports its peak as ``peak_bytes_reserved``
         peak = max(int(s.get("peak_bytes_in_use", 0))
                    + int(s.get("peak_bytes_reserved", 0)) for s in stats)
-    picks = sweep.check_cells(seed, n_rows, n_seeds, CHECK_CELLS)
-    prog = check.gather(checked, picks) if checked is not None else None
-    checked = None
 
     result = {"correct": False, "attempted": attempted, "failed": failed}
     device = {"platform": devices[0].platform,
@@ -206,7 +216,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             if m["name"] == "setup_s":
                 metrics["setup_s"] = {"value": setup_s, "unit": m["unit"]}
             elif m["name"] == "cell_rounds_per_s":
-                metrics[m["name"]] = {"value": done / (w1 - w0),
+                metrics[m["name"]] = {"value": done / window,
                                       "unit": m["unit"]}
 
     # -- the check, once the program's state is gone -------------------------
@@ -242,13 +252,17 @@ def _trace_context(cell, trace_dir, tel_dirs, marks, devices, cells,
                    sweeps):
     """What the per-layer readers read: device ops per chip over the
     traced window, the program's telemetry spans on the profiler's clock,
-    and the traced work."""
+    and the traced work.  The window runs from the first sweep's start to
+    the last sweep's end, less the copy of the checked cells."""
     events = xtrace.load_xplane(trace_dir)
-    first = {}
+    first, held = {}, []
     for e in sorted(events, key=lambda e: e["start"]):
-        if e["name"] == "bench.sweep" and \
-                not e["plane"].startswith(xtrace.DEVICE_PREFIX):
+        if e["plane"].startswith(xtrace.DEVICE_PREFIX):
+            continue
+        if e["name"] == "bench.sweep":
             first.setdefault(e["stats"].get("sweep", len(first)), e)
+        elif e["name"] == "bench.copy":
+            held.append((e["start"], e["start"] + e["dur"]))
     ann = [first[k] for k in sorted(first)]
     planes = xtrace.device_planes(events)[:len(devices)]
     ctx = types.SimpleNamespace(
@@ -267,18 +281,22 @@ def _trace_context(cell, trace_dir, tel_dirs, marks, devices, cells,
         return ctx
     offset = float(np.median([a["start"] - m[0] * 1e9
                               for a, m in zip(ann, marks)]))
-    t0, t1 = ann[0]["start"], ann[-1]["start"] + ann[-1]["dur"]
-    ctx.window_s = (t1 - t0) / 1e9
+    pieces = xtrace.without(ann[0]["start"],
+                            ann[-1]["start"] + ann[-1]["dur"], held)
+    ctx.window_s = sum(b - a for a, b in pieces) / 1e9
     ctx.telemetry = xtrace.telemetry_spans(tel_dirs, offset)
     ctx.spans = ctx.telemetry + [{"kind": "bench.sweep", "start": a["start"],
                                   "end": a["start"] + a["dur"]} for a in ann]
-    ctx.ops = {p: xtrace.device_ops(events, p, t0, t1) for p in planes}
+    by_piece = {p: [xtrace.device_ops(events, p, a, b) for a, b in pieces]
+                for p in planes}
+    ctx.ops = {p: [e for ops in by_piece[p] for e in ops] for p in planes}
     if planes:
         busy = [xtrace.busy_ns(ctx.ops[p]) / 1e9 for p in planes]
         ctx.busy_s = float(np.mean(busy))
         gaps = []
         for p in planes:
-            gaps += xtrace.idle_gaps(ctx.ops[p], ctx.spans, t0, t1)
+            for (a, b), ops in zip(pieces, by_piece[p]):
+                gaps += xtrace.idle_gaps(ops, ctx.spans, a, b)
             _log(f"# {p}: busy {busy[planes.index(p)]:.6f} s of "
                  f"{ctx.window_s:.6f} s")
         gaps.sort(key=lambda g: -g[1])

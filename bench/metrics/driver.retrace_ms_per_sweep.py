@@ -1,7 +1,8 @@
 """driver.retrace_ms_per_sweep: milliseconds per sweep in the program's
 ``chunk_compile`` spans, the calls that grow a chunk's compile cache
-(trace, lowering and compile or persistent-cache fetch) — paid again in
-every ``run_fleet_task`` call, since each builds new jitted chunks."""
+(trace, lowering and compile or persistent-cache fetch).  The driver
+reuses the warm-up sweep's chunks across ``run_fleet_task`` calls, so a
+window sweep reads 0: the guard that a window sweep compiles nothing."""
 from bench import xtrace
 
 

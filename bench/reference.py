@@ -5,23 +5,29 @@ weights for the traffic's rounds.  Each round, written from the paper's
 description (eqs. (3)-(6)) and imports nothing of the program:
 
     key, sub = split(key);  k_fade, k_ota, k_batch = split(sub, 3)
-    each device m: a minibatch drawn uniformly with replacement from its
-        shard (k_batch), or its whole shard; g_m = grad of the loss,
-        clipped to global norm G_max
     h = the channel draw, CN(0, Lambda) or Rician with factor K (k_fade)
     k_coeff, k_noise = split(k_ota)
     (s, noise_scale) = the scheme's coefficient rule on |h| (k_coeff)
+    minibatch indices of every device in one draw: uniform with
+        replacement from its shard (k_batch), or its whole shard
+    each device m in turn: g_m = grad of the loss, clipped to global
+        norm G_max; sum_m s_m g_m accumulated in f32
     ghat = sum_m s_m g_m + noise_scale * z,  z ~ N(0, I) drawn per leaf
-        from split(k_noise, leaves) in the leaves' sorted-name order
+        from split(k_noise, leaves) in the tree's flatten order (for a
+        flat dict, its sorted names)
     params = params - eta * ghat
 
-The evaluation after each eval round gives the global loss and the test
-logits; the accuracy is the argmax on the host.  The design constants of
-each scheme (pre-scalers, thresholds, masks, power limits) are inputs of
-the cell, like the data.
+The devices are folded one at a time in a scan, and the checked cells
+run one after another, so a check holds one cell's params, the
+accumulator, one device's gradient and activations, whatever the number
+of devices or cells.  The evaluation after each eval round gives the
+global loss and the test logits; the accuracy is the argmax on the host.
+The design constants of each scheme (pre-scalers, thresholds, masks,
+power limits) are inputs of the cell, like the data.  Parameters may be
+any tree; a leaf is named by its key path (``leaf_names``).
 
-``simulate`` runs a few cells side by side (vmapped), at ``highest``
-matmul precision for the reference, or in a lower dtype for the control.
+``simulate`` runs at ``highest`` matmul precision for the reference, or
+in a lower dtype for the control.
 """
 from __future__ import annotations
 
@@ -108,45 +114,61 @@ def _coeffs(c, habs, key, n: int, grid: int):
     return s.astype(habs.dtype), jnp.asarray(ns, habs.dtype)
 
 
+def leaf_names(tree) -> list:
+    """Each leaf's key path joined with ``/``, in the tree's flatten order
+    (for a flat dict: its keys, sorted)."""
+    return [jax.tree_util.keystr(path, simple=True, separator="/")
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _leaf_norms(tree):
+    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(l.astype(jnp.float32))))
+                      for l in jax.tree.leaves(tree)])
+
+
 def _round(model, cfg, batch: int, gmax: float, grid: int, dtype,
            keep: float, params, key, data, c):
     x, y = data
     n, m = x.shape[0], x.shape[1]
     key, sub = jax.random.split(key)
     k_fade, k_ota, k_batch = jax.random.split(sub, 3)
-    if 0 < batch < m:
-        idx = jax.random.randint(k_batch, (n, batch), 0, m)
-        x = jax.vmap(lambda xm, im: xm[im])(x, idx)
-        y = jax.vmap(lambda ym, im: ym[im])(y, idx)
-    if keep < 1.0:
-        x, y = x[:, :int(keep * x.shape[1])], y[:, :int(keep * y.shape[1])]
-
-    def device(xm, ym):
-        g = jax.grad(model.loss)(params, (xm, ym), cfg)
-        leaf = jnp.stack([jnp.sqrt(jnp.sum(jnp.square(l.astype(jnp.float32))))
-                          for l in jax.tree.leaves(g)])
-        norm = jnp.sqrt(jnp.sum(leaf ** 2))
-        scale = jnp.minimum(1.0, gmax / jnp.maximum(norm, 1e-12))
-        return jax.tree.map(lambda l: (l * scale).astype(dtype), g), norm, leaf
-
-    grads, norms, leaf_norms = jax.vmap(device)(x, y)
     h = _fading(k_fade, c["gains"], c["k_factor"])
     k_coeff, k_noise = jax.random.split(k_ota)
     s, ns = _coeffs(c, jnp.abs(h), k_coeff, n, grid)
     s, ns = s.astype(dtype), ns.astype(dtype)
+    idx, rows = None, m
+    if 0 < batch < m:
+        idx, rows = jax.random.randint(k_batch, (n, batch), 0, m), batch
+    rows = int(keep * rows)
+
+    def device(carry, per):
+        acc, norm_sum, leaf_sum = carry
+        xm, ym, sm, im = per
+        if im is not None:
+            xm, ym = xm[im], ym[im]
+        g = jax.grad(model.loss)(params, (xm[:rows], ym[:rows]), cfg)
+        leaf = _leaf_norms(g)
+        norm = jnp.sqrt(jnp.sum(leaf ** 2))
+        scale = jnp.minimum(1.0, gmax / jnp.maximum(norm, 1e-12))
+        g = jax.tree.map(lambda l: (l * scale).astype(dtype), g)
+        acc = jax.tree.map(lambda a, l: a + (sm * l).astype(jnp.float32),
+                           acc, g)
+        return (acc, norm_sum + norm, leaf_sum + leaf), None
+
     p_leaves, treedef = jax.tree.flatten(params)
-    g_leaves = jax.tree.leaves(grads)
+    zero = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            jnp.zeros((), jnp.float32), jnp.zeros(len(p_leaves), jnp.float32))
+    (acc, norm_sum, leaf_sum), _ = jax.lax.scan(device, zero, (x, y, s, idx))
     keys = jax.random.split(k_noise, len(p_leaves))
     out = []
-    for p, g, k in zip(p_leaves, g_leaves, keys):
+    for p, a, k in zip(p_leaves, jax.tree.leaves(acc), keys):
         z = jax.random.normal(k, (p.size,), jnp.float32).reshape(p.shape)
-        ghat = (jnp.sum(s.reshape((-1,) + (1,) * p.ndim) * g, axis=0)
-                + ns * z.astype(dtype))
+        ghat = a.astype(dtype) + ns * z.astype(dtype)
         out.append((p - c["eta"].astype(dtype) * ghat).astype(dtype))
-    metrics = {"grad_norm_mean": jnp.mean(norms),
+    metrics = {"grad_norm_mean": norm_sum / n,
                "noise_scale": ns.astype(jnp.float32),
                "active_devices": jnp.sum((s > 0).astype(jnp.float32)),
-               "leaf_grad": jnp.mean(leaf_norms, axis=0)}
+               "leaf_grad": leaf_sum / n}
     return jax.tree.unflatten(treedef, out), key, metrics
 
 
@@ -167,68 +189,100 @@ def cell_rows(coeffs: list, fading: list, etas: list, rows: list) -> dict:
     return out
 
 
-def simulate(model, cfg: dict, data: dict, params0: dict, cells: dict,
-             seeds: list, *, rounds: int, every: int, batch: int,
-             gmax: float, grid: int = 128, dtype=jnp.float32,
-             precision="highest", keep: float = 1.0) -> dict:
-    """Train the checked cells side by side; returns host arrays:
-
-    params    [leaf name -> [C, ...]] after the last round
-    evals     [(round, {"global_loss": [C], "acc": [C]})]
-    traces    {"grad_norm_mean", "noise_scale", "active_devices": [C, T]}
-    leaf_grad [C, L] mean per-device gradient norm of each leaf in round 0
-
-    ``keep`` < 1 trains on that leading share of each device's batch only
-    (a planted fault for the calibration, never the reference itself).
-    """
-    num = len(seeds)
+def make_chunk(model, cfg: dict, *, batch: int, gmax: float, grid: int,
+               dtype=jnp.float32, keep: float = 1.0):
+    """The jitted ``chunk(params, key, cell, data, length)`` of one cell:
+    ``length`` rounds in a scan, returning (params, key, the per-round
+    metrics).  ``params`` is donated."""
     round_fn = functools.partial(_round, model, cfg, batch, gmax, grid, dtype,
                                  keep)
-    xd = (jnp.asarray(data["train_x"], dtype), jnp.asarray(data["train_y"]))
-    gx = jnp.asarray(data["global_x"], dtype)
-    gy = jnp.asarray(data["global_y"])
-    tx = jnp.asarray(data["test_x"], dtype)
 
     # the data are arguments, not closed over: a closed-over array would
     # be compiled into the program as a constant
-    @functools.partial(jax.jit, static_argnames=("length",))
-    def chunk(params, keys, cells, xd, length):
-        def one(p, k, c):
-            def step(carry, _):
-                p, k = carry
-                p, k, met = round_fn(p, k, xd, c)
-                return (p, k), met
-            (p, k), met = jax.lax.scan(step, (p, k), None, length=length)
-            return p, k, met
-        return jax.vmap(one)(params, keys, cells)
+    @functools.partial(jax.jit, static_argnames=("length",),
+                       donate_argnums=0)
+    def chunk(params, key, cell, data, length):
+        def step(carry, _):
+            p, k = carry
+            p, k, met = round_fn(p, k, data, cell)
+            return (p, k), met
+        (params, key), met = jax.lax.scan(step, (params, key), None,
+                                          length=length)
+        return params, key, met
+    return chunk
+
+
+def _as_input(a, dtype):
+    """``a`` on the device, in ``dtype`` where it holds floats: token ids
+    and labels keep their integer type."""
+    a = jnp.asarray(a)
+    return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a
+
+
+def simulate(model, cfg: dict, data: dict, params0, cells: dict,
+             seeds: list, *, rounds: int, every: int, batch: int,
+             gmax: float, grid: int = 128, dtype=jnp.float32,
+             precision="highest", keep: float = 1.0) -> dict:
+    """Train the checked cells one after another; returns host arrays:
+
+    params    {leaf name: [C, ...]} after the last round, named and
+              ordered by ``leaf_names``
+    evals     [(round, {"global_loss": [C], "acc": [C]})]; ``acc`` is the
+              share of right argmax answers over every test target
+    traces    {"grad_norm_mean", "noise_scale", "active_devices": [C, T]}
+    leaf_grad [C, L] mean per-device gradient norm of each leaf in round 0
+
+    One compiled chunk per chunk length and one compiled eval serve every
+    cell, so the device holds one cell's state at a time.  ``keep`` < 1
+    trains on that leading share of each device's batch only (a planted
+    fault for the calibration, never the reference itself).
+    """
+    chunk = make_chunk(model, cfg, batch=batch, gmax=gmax, grid=grid,
+                       dtype=dtype, keep=keep)
 
     @jax.jit
     def evaluate(params, gx, gy, tx):
-        def one(p):
-            return model.loss(p, (gx, gy), cfg), model.logits(p, tx)
-        return jax.vmap(one)(params)
+        return model.loss(params, (gx, gy), cfg), model.logits(params, tx)
 
+    xd = (_as_input(data["train_x"], dtype), _as_input(data["train_y"], dtype))
+    gx, gy, tx = (_as_input(data[k], dtype)
+                  for k in ("global_x", "global_y", "test_x"))
+    test_y = np.asarray(data["test_y"])
+    names = leaf_names(params0)
+    points = eval_rounds(rounds, every)
     ctx = jax.default_matmul_precision(precision) if precision \
         else contextlib.nullcontext()
-    test_y = np.asarray(data["test_y"])
-    params = jax.tree.map(
-        lambda a: jnp.broadcast_to(jnp.asarray(a, dtype),
-                                   (num,) + jnp.shape(a)), params0)
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    evals, mets, t = [], [], -1
+    runs = []
     with ctx:
-        for point in eval_rounds(rounds, every):
-            params, keys, met = chunk(params, keys, cells, xd,
-                                      length=point - t)
-            mets.append(jax.tree.map(np.asarray, met))
-            t = point
-            loss, logits = evaluate(params, gx, gy, tx)
-            pred = np.argmax(np.asarray(logits, np.float32), axis=-1)
-            evals.append((t, {"global_loss": np.asarray(loss, np.float64),
-                              "acc": np.mean(pred == test_y[None], axis=-1)}))
-    traces = {k: np.concatenate([m[k] for m in mets], axis=1)
-              for k in ("grad_norm_mean", "noise_scale", "active_devices")}
-    return {"params": {k: np.asarray(v, np.float32)
-                       for k, v in params.items()},
-            "evals": evals, "traces": traces,
-            "leaf_grad": mets[0]["leaf_grad"][:, 0]}
+        for c, seed in enumerate(seeds):
+            cell = jax.tree.map(lambda a: a[c], cells)
+            # a copy, since the chunk donates it
+            params = jax.tree.map(lambda a: jnp.array(a, dtype), params0)
+            key = jax.random.PRNGKey(int(seed))
+            mets, losses, accs, t = [], [], [], -1
+            for point in points:
+                params, key, met = chunk(params, key, cell, xd,
+                                         length=point - t)
+                mets.append(jax.device_get(met))
+                t = point
+                loss, logits = evaluate(params, gx, gy, tx)
+                pred = np.argmax(np.asarray(logits, np.float32), axis=-1)
+                losses.append(float(loss))
+                accs.append(float(np.mean(pred == test_y)))
+            runs.append({
+                "params": [np.asarray(l, np.float32)
+                           for l in jax.tree.leaves(params)],
+                "mets": mets, "loss": losses, "acc": accs})
+            params = None
+    return {"params": {k: np.stack([r["params"][i] for r in runs])
+                       for i, k in enumerate(names)},
+            "evals": [(t, {"global_loss": np.asarray([r["loss"][i]
+                                                      for r in runs]),
+                           "acc": np.asarray([r["acc"][i] for r in runs])})
+                      for i, t in enumerate(points)],
+            "traces": {k: np.stack([np.concatenate([m[k] for m in r["mets"]])
+                                    for r in runs])
+                       for k in ("grad_norm_mean", "noise_scale",
+                                 "active_devices")},
+            "leaf_grad": np.stack([r["mets"][0]["leaf_grad"][0]
+                                   for r in runs])}

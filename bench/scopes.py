@@ -1,13 +1,6 @@
-"""What the per-layer readers of compile phases and name scopes share.
+"""What the per-layer readers of name scopes share.
 
-Compile phases.  While its telemetry is on, the program writes JAX's
-compile phases as spans: ``compile.jaxpr_trace``, ``compile.lower`` and
-``compile.backend``.  A ``jax.jit`` traced or lowered inside another's
-phase emits a span of its own inside the outer one, so only the
-outermost spans (those no other ``compile.*`` span holds) are counted,
-each stretch of time once and under the phase that holds it.
-
-Name scopes.  The program names the layers of its round step with
+The program names the layers of its round step with
 ``jax.named_scope`` (``fl.grad``, ``fl.channel``, ``fl.uplink``,
 ``fl.step``, ``fl.eval``).  The harness writes the traced run's profile
 to ``<root>/.bench_out/<cell>/trace``; the xprof converter's
@@ -28,44 +21,7 @@ import json
 import os
 import re
 
-COMPILE_PREFIX = "compile."
 SCOPE = re.compile(r"(?<![\w.])fl\.[A-Za-z_]+")
-
-
-def outermost(spans: list) -> list:
-    """The ``compile.*`` spans that no other ``compile.*`` span holds.
-
-    Spans are ``xtrace.telemetry_spans`` dicts; the program's
-    ``t0_ns``/``t1_ns`` stamps in ``fields`` decide the nesting where
-    present (they are exact, where ``start``/``end`` carry microsecond
-    rounding)."""
-    def bounds(s):
-        f = s.get("fields") or {}
-        if "t0_ns" in f and "t1_ns" in f:
-            return float(f["t0_ns"]), float(f["t1_ns"])
-        return s["start"], s["end"]
-
-    comp = sorted((bounds(s) + (s,) for s in spans
-                   if s["kind"].startswith(COMPILE_PREFIX)),
-                  key=lambda b: (b[0], -b[1]))
-    out, reach = [], float("-inf")
-    for a, b, s in comp:
-        if b <= reach:
-            continue
-        out.append(s)
-        reach = b
-    return out
-
-
-def phase_ms_per_sweep(ctx, kind: str):
-    """Milliseconds per traced sweep in the outermost spans of ``kind``,
-    or None where the program wrote no ``compile.*`` span."""
-    if not ctx.sweeps or not any(s["kind"].startswith(COMPILE_PREFIX)
-                                 for s in ctx.telemetry):
-        ctx.log(f"# {kind}: no compile-phase spans in the telemetry")
-        return None
-    return 1e3 * sum(s["dur"] for s in outermost(ctx.telemetry)
-                     if s["kind"] == kind) / ctx.sweeps
 
 
 def scope_of(name_stack) -> str | None:

@@ -107,11 +107,12 @@ def fleet_seeds(seed: int, sweep: int, count: int, warmup=False) -> tuple:
 
 def check_cells(seed: int, rows: int, seeds: int, count: int) -> list:
     """``count`` (row, seed-column) cells of a [rows, seeds] grid drawn
-    from ``seed``: one from each of ``count`` equal strata of the
-    flattened cell axis, so that on a sharded grid every chip's block of
-    cells is checked."""
+    from ``seed``, or every cell of a smaller grid: one from each of as
+    many equal strata of the flattened cell axis, so that on a sharded
+    grid every chip's block of cells is checked."""
     rng = np.random.default_rng(_entropy(seed) + [2])
     total = rows * seeds
+    count = min(count, total)
     edges = np.linspace(0, total, count + 1).astype(int)
     flat = [int(rng.integers(a, b)) for a, b in zip(edges[:-1], edges[1:])]
     return [(f // seeds, f % seeds) for f in flat]

@@ -57,6 +57,25 @@ def test_check_cells_cover_every_chip_block():
     assert blocks == [0, 1, 2, 3]
 
 
+@pytest.mark.parametrize("seed, picks", [
+    (1, [(0, 8), (2, 1), (3, 6), (5, 3)]),
+    (99, [(1, 2), (3, 1), (3, 8), (6, 11)]),
+    (2 ** 31 + 12345, [(1, 8), (2, 3), (4, 1), (5, 8)]),
+    (3000000001, [(1, 4), (3, 1), (4, 9), (5, 3)]),
+])
+def test_check_cells_of_the_84_cell_grid_are_pinned(seed, picks):
+    # the picks of the 7 x 12 grids of both cells, as every run since the
+    # benchmark began has drawn them
+    assert sweep.check_cells(seed, 7, 12, 4) == picks
+
+
+@pytest.mark.parametrize("rows, seeds", [(1, 2), (2, 1), (1, 3)])
+def test_check_cells_of_a_small_grid_are_every_cell(rows, seeds):
+    picks = sweep.check_cells(5, rows, seeds, 4)
+    assert sorted(picks) == [(r, s) for r in range(rows)
+                             for s in range(seeds)]
+
+
 def _reader(name):
     return sweep.load_module(os.path.join(BENCH, "metrics", name + ".py"),
                              "test_metric_" + name.replace(".", "_"))
@@ -75,6 +94,18 @@ def test_step_mfu_arithmetic():
     cell.traffic = {"batch_size": 0}           # full batch: 1000 a device
     assert _reader("model.step_mfu").read(ctx) == \
         pytest.approx(100 * flops / 128 * 1000 / (2.0 * 197e12))
+    # a configuration module that counts its own samples per device
+    counts = []
+
+    def samples_per_device(cfg, batch):
+        counts.append(batch)
+        return 7
+    cell.model = types.SimpleNamespace(
+        flops_per_sample=cell.model.flops_per_sample,
+        samples_per_device=samples_per_device)
+    assert _reader("model.step_mfu").read(ctx) == \
+        pytest.approx(100 * flops / 128 * 7 / (2.0 * 197e12))
+    assert counts == [0]
 
 
 def test_roofline_arithmetic():

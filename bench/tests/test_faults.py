@@ -24,6 +24,19 @@ def bench(tmp_path_factory):
     return tiny.make_root(str(tmp_path_factory.mktemp("faults")))
 
 
+@pytest.fixture(autouse=True)
+def fresh_chunks():
+    """The driver reuses a jitted chunk across calls in one process, keyed
+    on what the chunk closes over and not on the round body: each test
+    starts and ends with no cached chunk, so a fault planted in the body
+    is traced into the run and stays out of the next test."""
+    from repro.fl import driver
+
+    driver.clear_chunk_cache()
+    yield
+    driver.clear_chunk_cache()
+
+
 def _run(bench, cell="tiny_mlp"):
     return harness.run(cell, 11, 0.5, False, bench, time.monotonic(),
                        require_chip=False)

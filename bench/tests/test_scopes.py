@@ -1,4 +1,4 @@
-"""The readers of compile-phase spans and of device time by name scope."""
+"""The readers of device time by name scope."""
 import os
 import types
 
@@ -9,21 +9,11 @@ from bench import scopes, sweep
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def span(kind, t0, t1, **fields):
-    """A telemetry span as ``xtrace.telemetry_spans`` gives it (seconds
-    in, nanoseconds on the clock); ``fields`` holds the program's own
-    wall-clock stamps."""
-    return {"kind": kind, "start": t0 * 1e9, "end": t1 * 1e9,
-            "dur": t1 - t0,
-            "fields": {"ev": kind, "t0_ns": int(t0 * 1e9),
-                       "t1_ns": int(t1 * 1e9), **fields}}
-
-
-def ctx_of(spans=(), sweeps=2, rounds=150, planes=(), chips=1, busy_s=None):
+def ctx_of(sweeps=2, rounds=150, planes=(), chips=1, busy_s=None):
     logged = []
     ctx = types.SimpleNamespace(
-        telemetry=list(spans), sweeps=sweeps, rounds=rounds,
-        planes=list(planes), chips=chips, busy_s=busy_s,
+        sweeps=sweeps, rounds=rounds, planes=list(planes), chips=chips,
+        busy_s=busy_s,
         cell=types.SimpleNamespace(root="/nonexistent", name="cell"),
         log=logged.append)
     return ctx, logged
@@ -32,64 +22,6 @@ def ctx_of(spans=(), sweeps=2, rounds=150, planes=(), chips=1, busy_s=None):
 def reader(name):
     return sweep.load_module(os.path.join(BENCH, "metrics", name + ".py"),
                              "test_metric_" + name.replace(".", "_"))
-
-
-# one chunk call: the chunk's trace holds two nested jit traces, its
-# lowering traces one more jit, then the backend step; the eval compiles
-# after it, outside
-SWEEP = [span("chunk_compile", 10.0, 15.0),
-         span("compile.jaxpr_trace", 10.2, 10.3, fun="add"),
-         span("compile.jaxpr_trace", 10.4, 10.45, fun="less"),
-         span("compile.jaxpr_trace", 10.0, 11.0, fun="fleet_chunk"),
-         span("compile.jaxpr_trace", 11.5, 11.6, fun="_threefry_split"),
-         span("compile.lower", 11.0, 12.0, fun="jit(fleet_chunk)"),
-         span("compile.backend", 12.0, 14.5, fun="jit(fleet_chunk)"),
-         span("chunk_exec", 15.0, 16.0),
-         span("eval", 16.0, 16.5),
-         span("compile.jaxpr_trace", 16.0, 16.1, fun="evaluate"),
-         span("compile.lower", 16.1, 16.15, fun="jit(evaluate)"),
-         span("compile.backend", 16.15, 16.35, fun="jit(evaluate)")]
-
-
-def test_nested_compile_spans_are_counted_once():
-    kept = scopes.outermost(SWEEP)
-    assert [(s["kind"], s["fields"]["fun"]) for s in kept] == [
-        ("compile.jaxpr_trace", "fleet_chunk"),
-        ("compile.lower", "jit(fleet_chunk)"),
-        ("compile.backend", "jit(fleet_chunk)"),
-        ("compile.jaxpr_trace", "evaluate"),
-        ("compile.lower", "jit(evaluate)"),
-        ("compile.backend", "jit(evaluate)")]
-
-
-def test_phase_readers_per_sweep():
-    ctx, _ = ctx_of(SWEEP, sweeps=1)
-    got = {name: reader(name).read(ctx) for name in (
-        "driver.jaxpr_trace_ms_per_sweep", "driver.lower_ms_per_sweep",
-        "driver.backend_compile_ms_per_sweep")}
-    assert got == pytest.approx({
-        "driver.jaxpr_trace_ms_per_sweep": 1000.0 + 100.0,
-        "driver.lower_ms_per_sweep": 1000.0 + 50.0,
-        "driver.backend_compile_ms_per_sweep": 2500.0 + 200.0})
-    ctx, _ = ctx_of(SWEEP, sweeps=2)
-    assert reader("driver.lower_ms_per_sweep").read(ctx) == \
-        pytest.approx(525.0)
-
-
-def test_outermost_falls_back_to_the_harness_clock():
-    bare = [{**s, "fields": {}} for s in SWEEP]
-    assert [s["fields"] for s in scopes.outermost(bare)] == [{}] * 6
-
-
-def test_no_compile_span_reads_none_not_zero():
-    # a program that writes no compile-phase spans (the parent of the
-    # change that added them): the reader has nothing to read
-    ctx, logged = ctx_of([span("chunk_compile", 0.0, 4.0),
-                          span("eval", 4.0, 4.2)])
-    assert reader("driver.jaxpr_trace_ms_per_sweep").read(ctx) is None
-    assert logged
-    ctx, _ = ctx_of([], sweeps=0)
-    assert scopes.phase_ms_per_sweep(ctx, "compile.lower") is None
 
 
 @pytest.mark.parametrize("stack, scope", [

@@ -20,6 +20,16 @@ def test_union_and_busy_merge_overlaps():
     assert xtrace.busy_ns(ops) == 20.0
 
 
+@pytest.mark.parametrize("holes, pieces", [
+    ([], [(0, 100)]),
+    ([(40, 45)], [(0, 40), (45, 100)]),
+    ([(40, 45), (44, 50), (-5, 3), (99, 120)], [(3, 40), (50, 99)]),
+    ([(200, 300)], [(0, 100)]),
+])
+def test_a_window_less_its_holes(holes, pieces):
+    assert xtrace.without(0, 100, holes) == pieces
+
+
 def test_device_ops_clip_to_window_and_plane():
     events = [op("a", 0, 10), op("b", 8, 10), op("c", 30, 5),
               op("d", 0, 100, plane="/device:TPU:1"),

@@ -78,6 +78,22 @@ def union(intervals: list) -> list:
     return merged
 
 
+def without(t0: float, t1: float, holes: list) -> list:
+    """[t0, t1] less the intervals ``holes`` (pairs): the pieces left, as
+    sorted (start, end) pairs."""
+    out, cursor = [], t0
+    for a, b in union(holes):
+        a, b = max(a, t0), min(b, t1)
+        if b <= a:
+            continue
+        if a > cursor:
+            out.append((cursor, a))
+        cursor = max(cursor, b)
+    if t1 > cursor:
+        out.append((cursor, t1))
+    return out
+
+
 def busy_ns(ops: list) -> float:
     """Nanoseconds in which at least one of ``ops`` ran."""
     return sum(b - a for a, b in
